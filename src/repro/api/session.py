@@ -90,6 +90,10 @@ class Session:
         # train/bench/serve/tune, inspectable after the Report comes back
         self.last_tracer: Optional[Tracer] = None
         self.last_metrics: Optional[MetricsRegistry] = None
+        # what the last run produced: final parameters (train/bench) and
+        # every generated token stream by request id (serve)
+        self.last_params: Any = None
+        self.last_tokens: Dict[int, np.ndarray] = {}
 
     # ------------------------------------------------------------------
     def _make_obs(self) -> Tuple[Tracer, MetricsRegistry]:
@@ -262,14 +266,10 @@ class Session:
             import jax
 
             from repro.distributed import PipelineTrainer
+            from repro.launch.device import take_devices
 
-            devs = jax.devices()
-            world = spec.dp or len(devs)
-            if len(devs) < world:
-                raise RuntimeError(
-                    f"pipe={spec.pipe} on {world} devices but only "
-                    f"{len(devs)} visible; set XLA_FLAGS="
-                    f"--xla_force_host_platform_device_count={world}")
+            world = spec.dp or jax.device_count()
+            devs = take_devices(world, f"pipe={spec.pipe}")
             # the 1F1B schedule owns microbatching — the planner's
             # accumulation knob must not nest another scan inside a stage
             run = _dc.replace(run, microbatch=0)
@@ -278,22 +278,15 @@ class Session:
             trainer = PipelineTrainer(
                 self.cfg, run, opt, pipe=spec.pipe,
                 n_microbatch=spec.n_microbatch, strategy=strategy,
-                compression=spec.compress, devices=devs[:world],
+                compression=spec.compress, devices=devs,
                 tracer=tracer, metrics=metrics)
             res = trainer.train(**loop_kw)
             sync_rep = trainer.report()
             pipe_rep = trainer.pipeline_report()
         elif spec.dp and (spec.staleness or spec.backup_workers):
-            import jax
-
             from repro.distributed import AsyncPSTrainer
+            from repro.launch.device import take_devices
 
-            devs = jax.devices()
-            if len(devs) < spec.dp:
-                raise RuntimeError(
-                    f"dp={spec.dp} but only {len(devs)} devices visible; set "
-                    f"XLA_FLAGS=--xla_force_host_platform_device_count="
-                    f"{spec.dp}")
             # bounded staleness is a parameter-server schedule by
             # construction; "auto" resolves to it rather than the planner's
             # all-reduce pick
@@ -302,25 +295,19 @@ class Session:
             trainer = AsyncPSTrainer(
                 self.cfg, run, opt, staleness=spec.staleness,
                 backup_workers=spec.backup_workers, strategy=strategy,
-                compression=spec.compress, devices=devs[:spec.dp],
+                compression=spec.compress,
+                devices=take_devices(spec.dp, f"dp={spec.dp}"),
                 tracer=tracer, metrics=metrics)
             res = trainer.train(**loop_kw)
             sync_rep = trainer.report()
             async_rep = trainer.async_report()
         elif spec.dp:
-            import jax
-
-            from repro.distributed import DataParallelTrainer
-
-            devs = jax.devices()
-            if len(devs) < spec.dp:
-                raise RuntimeError(
-                    f"dp={spec.dp} but only {len(devs)} devices visible; set "
-                    f"XLA_FLAGS=--xla_force_host_platform_device_count="
-                    f"{spec.dp}")
             from repro.core.ps import DEFAULT_BUCKET_MB
+            from repro.distributed import DataParallelTrainer
+            from repro.launch.device import take_devices
 
-            kw = dict(compression=spec.compress, devices=devs[:spec.dp],
+            kw = dict(compression=spec.compress,
+                      devices=take_devices(spec.dp, f"dp={spec.dp}"),
                       topology=self.cluster,
                       sync_overlap=spec.sync_overlap,
                       bucket_mb=spec.bucket_mb or DEFAULT_BUCKET_MB,
@@ -346,6 +333,7 @@ class Session:
                 metrics.observe("train/param_update_s", t.param_update)
                 metrics.observe("train/step_s",
                                 t.compute + t.dist_update + t.param_update)
+        self.last_params = res.params
         measured = res.summary()
         metrics.set_gauge("train/tokens_per_s", measured["tokens_per_s"])
         metrics.set_gauge("train/r_o", measured["r_o"])
@@ -373,7 +361,7 @@ class Session:
             return self._serve_continuous()
         return self._serve_static()
 
-    def _serve_workload(self):
+    def serve_workload(self):
         """The seeded synthetic workload both serve modes share: ragged
         prompt lengths in [8, 48) and ragged ``n_new`` in
         [max(1, n_new/4), n_new] — raggedness is what separates the two
@@ -511,13 +499,14 @@ class Session:
                      tracer=tracer, metrics=metrics)
         sched = BatchScheduler(eng, max_batch=spec.max_batch)
         lengths, n_news = [], []
-        for prompt, n, n_new in self._serve_workload():
+        for prompt, n, n_new in self.serve_workload():
             sched.submit(prompt, n_new)
             lengths.append(n)
             n_news.append(n_new)
         t0 = monotonic()
         results = sched.run()
         wall = monotonic() - t0
+        self.last_tokens = results
         per_request = self._per_request(results, sched.latencies)
         n_tokens = sum(r["tokens"] for r in per_request)
         metrics.set_gauge("serve/wall_s", wall)
@@ -566,7 +555,7 @@ class Session:
         sched = ContinuousScheduler(eng, kv)
         arrivals = make_trace(spec.arrival, spec.requests, seed=spec.seed)
         lengths, n_news = [], []
-        for (prompt, n, n_new), step in zip(self._serve_workload(),
+        for (prompt, n, n_new), step in zip(self.serve_workload(),
                                             arrivals):
             sched.submit(prompt, n_new, arrival_step=step)
             lengths.append(n)
@@ -574,6 +563,7 @@ class Session:
         t0 = monotonic()
         results = sched.run()
         wall = monotonic() - t0
+        self.last_tokens = results
         per_request = self._per_request(results, sched.latencies)
         n_tokens = sum(r["tokens"] for r in per_request)
         metrics.set_gauge("serve/wall_s", wall)

@@ -106,9 +106,14 @@ def bench_kernels(*, seq: int = 128, repeats: int = 2,
     """Time every registered variant of every tunable op and pick the
     fastest one that runs.  Variants that raise are recorded (not fatal) —
     an algorithm that cannot execute on this backend is infeasible, which
-    is exactly what the paper's procedure prunes on."""
+    is exactly what the paper's procedure prunes on.  On a TPU a Pallas
+    variant that fails is a broken kernel, not an infeasible algorithm:
+    its error propagates instead of handing the op to the reference."""
+    import jax
+
     from repro.kernels import ops
 
+    on_tpu = jax.default_backend() == "tpu"
     out: Dict[str, Dict[str, Any]] = {}
     for op in ops.TUNABLE_OPS:
         inputs = ops.tune_inputs(op, seq=seq)
@@ -118,6 +123,8 @@ def bench_kernels(*, seq: int = 128, repeats: int = 2,
             try:
                 times[name] = _timeit(fn, *inputs, repeats=repeats)
             except Exception as e:  # infeasible variant: record, keep going
+                if on_tpu and name.startswith("pallas"):
+                    raise
                 errors[name] = f"{type(e).__name__}: {e}"
         chosen = min(times, key=times.get) if times else ""
         out[op] = {"chosen": chosen, "times_s": times, "errors": errors,
@@ -167,17 +174,12 @@ def measure_train_steps(cfg: ModelConfig, *, batch: int, seq: int,
     opt = OptConfig(lr=1e-3, warmup_steps=1, total_steps=max(steps, 1))
     sync_report = None
     if dp >= 2:
-        import jax
-
         from repro.distributed.trainer import DataParallelTrainer
+        from repro.launch.device import take_devices
 
-        devs = jax.devices()
-        if len(devs) < dp:
-            raise RuntimeError(f"dp={dp} but only {len(devs)} devices; set "
-                               "XLA_FLAGS=--xla_force_host_platform_device_"
-                               f"count={dp}")
         tr = DataParallelTrainer(cfg, run, opt, strategy="all_reduce",
-                                 devices=devs[:dp], topology=topology)
+                                 devices=take_devices(dp, f"dp={dp}"),
+                                 topology=topology)
         res = tr.train(batch=batch, seq=seq, steps=steps, seed=seed,
                        log_every=0)
         sync_report = tr.report().as_dict()

@@ -49,6 +49,7 @@ class Chip:
     hbm_bw: float  # bytes/s
     link_bw: float  # bytes/s per ICI/interconnect link
     vmem_bytes: float = 0.0
+    device_kind: str = ""  # jax.Device.device_kind of this chip ("" = none)
 
     CAL_SUFFIX = "+cal"
 
@@ -79,6 +80,7 @@ TPU_V5E = Chip(
     hbm_bw=819e9,
     link_bw=50e9,
     vmem_bytes=128 * 2**20,
+    device_kind="TPU v5 lite",
 )
 
 # Paper-era: NVIDIA GK210 (one half of a K80), AWS P2 instances (Table 1)
@@ -89,6 +91,20 @@ K80_GK210 = Chip(
     hbm_bw=240e9,
     link_bw=10e9 / 8,  # 10 Gbit Ethernet (p2.8xlarge "network" as PS link)
 )
+
+# chips a JAX device can be, keyed by what the device reports
+CHIPS_BY_KIND: Dict[str, Chip] = {TPU_V5E.device_kind: TPU_V5E}
+
+
+def chip_for_kind(device_kind: str) -> Chip:
+    """The :class:`Chip` describing a device of this ``device_kind``
+    (``jax.devices()[0].device_kind``).  An unknown kind is an error: a
+    plan priced on another chip's constants would be silently wrong."""
+    try:
+        return CHIPS_BY_KIND[device_kind]
+    except KeyError:
+        raise KeyError(f"no Chip describes device_kind {device_kind!r}; "
+                       f"known: {sorted(CHIPS_BY_KIND)}") from None
 
 
 # ---------------------------------------------------------------------------

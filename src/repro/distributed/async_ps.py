@@ -40,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core import ps as ps_lib
 from repro.distributed.collectives import SyncStrategy
@@ -138,8 +137,9 @@ class AsyncPSTrainer(DataParallelTrainer):
             # shard gets its own byte-copy of the server params)
             return _stack(p)
 
-        self._bcast_fn = jax.jit(shard_map(
-            bcast, mesh=mesh, in_specs=(P(),), out_specs=dspec))
+        self._bcast_fn = jax.jit(jax.shard_map(
+            bcast, mesh=mesh, in_specs=(P(),), out_specs=dspec,
+            check_vma=False))
 
         def refresh(mask, server, workers):
             # mask shard: (1,) bool; jnp.where copies bytes exactly, so a
@@ -149,9 +149,10 @@ class AsyncPSTrainer(DataParallelTrainer):
                 return jnp.where(m, s[None], w)
             return jax.tree_util.tree_map(sel, server, workers)
 
-        self._refresh_fn = jax.jit(shard_map(
+        self._refresh_fn = jax.jit(jax.shard_map(
             refresh, mesh=mesh,
-            in_specs=(dspec, P(), dspec), out_specs=dspec))
+            in_specs=(dspec, P(), dspec), out_specs=dspec,
+            check_vma=False))
 
         grads_of = build_grad_fn(self.cfg, self.run)
 
@@ -161,8 +162,9 @@ class AsyncPSTrainer(DataParallelTrainer):
             loss, _, grads = grads_of(_unstack(pstack), batch)
             return _stack((loss, grads))
 
-        self._wgrad_fn = jax.jit(shard_map(
-            wgrad, mesh=mesh, in_specs=(dspec, dspec), out_specs=dspec))
+        self._wgrad_fn = jax.jit(jax.shard_map(
+            wgrad, mesh=mesh, in_specs=(dspec, dspec), out_specs=dspec,
+            check_vma=False))
 
         def weight(gstack, w):
             # w shard: (1,) float32 — 1.0 for survivors scaled dp/(dp-k),
@@ -171,8 +173,9 @@ class AsyncPSTrainer(DataParallelTrainer):
                 return x * w.reshape((1,) + (1,) * (x.ndim - 1))
             return jax.tree_util.tree_map(mul, gstack)
 
-        self._weight_fn = jax.jit(shard_map(
-            weight, mesh=mesh, in_specs=(dspec, dspec), out_specs=dspec))
+        self._weight_fn = jax.jit(jax.shard_map(
+            weight, mesh=mesh, in_specs=(dspec, dspec), out_specs=dspec,
+            check_vma=False))
 
     # ------------------------------------------------------------------
     def _refresh_mask(self, t: int) -> np.ndarray:

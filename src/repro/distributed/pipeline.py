@@ -69,7 +69,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig, SlotSpec
 from repro.core.pipeline import (StepTimes, balanced_stage_cut,
                                  pipeline_bubble, schedule_1f1b,
@@ -315,10 +314,12 @@ class PipelineTrainer:
                     gp = jax.grad(solo)(sp, b)
                     return _stack(gp)
 
-                self._fwd_fns.append(jax.jit(shard_map(
-                    fwd_solo, mesh=mesh, in_specs=(P(), d), out_specs=d)))
-                self._bwd_fns.append(jax.jit(shard_map(
-                    bwd_solo, mesh=mesh, in_specs=(P(), d), out_specs=d)))
+                self._fwd_fns.append(jax.jit(jax.shard_map(
+                    fwd_solo, mesh=mesh, in_specs=(P(), d), out_specs=d,
+                    check_vma=False)))
+                self._bwd_fns.append(jax.jit(jax.shard_map(
+                    bwd_solo, mesh=mesh, in_specs=(P(), d), out_specs=d,
+                    check_vma=False)))
             elif s == 0:
                 def fwd_first(sp, b):
                     h, aux = first(sp, b)
@@ -336,21 +337,21 @@ class PipelineTrainer:
                         gp["embed"] = gp["embed"] + _unstack(gemb)
                         return _stack(gp)
 
-                    self._bwd_fns.append(jax.jit(shard_map(
+                    self._bwd_fns.append(jax.jit(jax.shard_map(
                         bwd_first, mesh=mesh, in_specs=(P(), d, d, d),
-                        out_specs=d)))
+                        out_specs=d, check_vma=False)))
                 else:
                     def bwd_first(sp, b, gy):
                         _, vjp = jax.vjp(lambda sp_: first(sp_, b), sp)
                         (gp,) = vjp((gy, cot_aux))
                         return _stack(gp)
 
-                    self._bwd_fns.append(jax.jit(shard_map(
+                    self._bwd_fns.append(jax.jit(jax.shard_map(
                         bwd_first, mesh=mesh, in_specs=(P(), d, d),
-                        out_specs=d)))
-                self._fwd_fns.append(jax.jit(shard_map(
+                        out_specs=d, check_vma=False)))
+                self._fwd_fns.append(jax.jit(jax.shard_map(
                     fwd_first, mesh=mesh, in_specs=(P(), d),
-                    out_specs=(d, d))))
+                    out_specs=(d, d), check_vma=False)))
             elif s < p - 1:
                 def fwd_mid(sp, h, aux):
                     h, aux = mid(sp, h, _unstack(aux))
@@ -363,12 +364,12 @@ class PipelineTrainer:
                     gp, gh = vjp((gy, cot_aux))
                     return _stack(gp), gh
 
-                self._fwd_fns.append(jax.jit(shard_map(
+                self._fwd_fns.append(jax.jit(jax.shard_map(
                     fwd_mid, mesh=mesh, in_specs=(P(), d, d),
-                    out_specs=(d, d))))
-                self._bwd_fns.append(jax.jit(shard_map(
+                    out_specs=(d, d), check_vma=False)))
+                self._bwd_fns.append(jax.jit(jax.shard_map(
                     bwd_mid, mesh=mesh, in_specs=(P(), d, d),
-                    out_specs=(d, d))))
+                    out_specs=(d, d), check_vma=False)))
             else:
                 def fwd_last(sp, b, h, aux):
                     return _stack(last(sp, b, h, _unstack(aux)))
@@ -386,9 +387,9 @@ class PipelineTrainer:
                         gemb = gp.pop("embed_out")
                         return _stack(gp), _stack(gemb), gh
 
-                    self._bwd_fns.append(jax.jit(shard_map(
+                    self._bwd_fns.append(jax.jit(jax.shard_map(
                         bwd_last, mesh=mesh, in_specs=(P(), d, d),
-                        out_specs=(d, d, d))))
+                        out_specs=(d, d, d), check_vma=False)))
                 else:
                     def bwd_last(sp, b, h):
                         gp, gh = jax.grad(
@@ -397,12 +398,12 @@ class PipelineTrainer:
                             argnums=(0, 1))(sp, h)
                         return _stack(gp), gh
 
-                    self._bwd_fns.append(jax.jit(shard_map(
+                    self._bwd_fns.append(jax.jit(jax.shard_map(
                         bwd_last, mesh=mesh, in_specs=(P(), d, d),
-                        out_specs=(d, d))))
-                self._fwd_fns.append(jax.jit(shard_map(
+                        out_specs=(d, d), check_vma=False)))
+                self._fwd_fns.append(jax.jit(jax.shard_map(
                     fwd_last, mesh=mesh, in_specs=(P(), d, d, d),
-                    out_specs=d)))
+                    out_specs=d, check_vma=False)))
 
         # per-stage gradient sync: divide the microbatch sum by m (exactly
         # build_grad_fn's gsum / n), compress, then the strategy's data-
@@ -415,9 +416,9 @@ class PipelineTrainer:
                 g, _ = comp.apply(g, None)
                 return strat.sync(g, "data", dp)
 
-            self._sync_fns.append(jax.jit(shard_map(
+            self._sync_fns.append(jax.jit(jax.shard_map(
                 sync_one, mesh=self.stage_meshes[s],
-                in_specs=(P("data"),), out_specs=P())))
+                in_specs=(P("data"),), out_specs=P(), check_vma=False)))
 
         # fp32 accumulators: zeros + g first (build_grad_fn starts from
         # zeros, and 0 + g is the baseline's first scan add), then g + g'

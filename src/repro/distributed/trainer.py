@@ -51,7 +51,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.core.hardware import ClusterSpec
 from repro.core.pipeline import StepTimes
@@ -271,9 +270,9 @@ class DataParallelTrainer:
             loss, _, grads = grads_of(params, batch)
             return _stack((loss, grads))
 
-        self._grad_fn = jax.jit(shard_map(
+        self._grad_fn = jax.jit(jax.shard_map(
             grad_phase, mesh=self.mesh,
-            in_specs=(P(), dspec), out_specs=dspec))
+            in_specs=(P(), dspec), out_specs=dspec, check_vma=False))
 
         def sync_phase(gstack, efstack):
             grads = _unstack(gstack)
@@ -285,10 +284,10 @@ class DataParallelTrainer:
 
         # ef may be None (stateless compressor): an empty pytree, for which
         # the data-axes prefix spec is vacuous
-        self._sync_fn = jax.jit(shard_map(
+        self._sync_fn = jax.jit(jax.shard_map(
             sync_phase, mesh=self.mesh,
             in_specs=(dspec, dspec),
-            out_specs=(P(), dspec)))
+            out_specs=(P(), dspec), check_vma=False))
 
         self._update_fn = jax.jit(
             lambda p, s, g: opt_lib.apply_updates(self.opt, p, g, s),
@@ -329,9 +328,10 @@ class DataParallelTrainer:
             ef_out = _stack(ef) if ef is not None else None
             return g, ef_out
 
-        self._bucket_sync_fn = jax.jit(shard_map(
+        self._bucket_sync_fn = jax.jit(jax.shard_map(
             bucket_sync, mesh=self.mesh,
-            in_specs=(dspec, dspec), out_specs=(P(), dspec)))
+            in_specs=(dspec, dspec), out_specs=(P(), dspec),
+            check_vma=False))
 
         def sync_all_buckets(p, b, efs):
             """shard_map body of the fused step: local grads, then one
@@ -359,10 +359,11 @@ class DataParallelTrainer:
             return _stack(loss), synced, ef_out
 
         def fused_step(params, opt_state, batch, efstack):
-            losses, grads, efs = shard_map(
+            losses, grads, efs = jax.shard_map(
                 sync_all_buckets, mesh=self.mesh,
                 in_specs=(P(), dspec, dspec),
-                out_specs=(dspec, P(), dspec))(params, batch, efstack)
+                out_specs=(dspec, P(), dspec),
+                check_vma=False)(params, batch, efstack)
             new_p, new_s, gnorm = opt_lib.apply_updates(
                 self.opt, params, grads, opt_state)
             return new_p, new_s, losses, efs, gnorm

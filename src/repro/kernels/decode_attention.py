@@ -2,8 +2,9 @@
 
 Grid (B, H, n_kv_blocks); kv sequential with running (m, l, acc) scratch —
 the single-chip analogue of the cross-shard partial-softmax combine the
-SPMD decode path performs. Per-example valid length arrives as a (B, 1)
-int32 array (position of the current token; cache entries > pos masked).
+SPMD decode path performs. Per-example position of the current token
+arrives as a (B,) int32 scalar-prefetch operand in SMEM (cache entries
+> pos masked).
 
 Layout: q (B, H, D), k/v (B, KV, S, D).
 
@@ -26,10 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
 NEG_INF = -2.0e38
 
@@ -86,23 +83,20 @@ def _flash_body(pos, ki, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale, cap, window, tk, nk):
-    _flash_body(pos_ref[0, 0], pl.program_id(2), q_ref, k_ref, v_ref, o_ref,
-                acc_ref, m_ref, l_ref, scale=scale, cap=cap, window=window,
-                tk=tk, nk=nk)
-
-
-def _paged_kernel(table_ref, pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                  m_ref, l_ref, *, scale, cap, window, tk, nk):
-    # table_ref routed the k/v BlockSpecs; the body only needs the position.
-    del table_ref
     _flash_body(pos_ref[pl.program_id(0)], pl.program_id(2), q_ref, k_ref,
                 v_ref, o_ref, acc_ref, m_ref, l_ref, scale=scale, cap=cap,
                 window=window, tk=tk, nk=nk)
 
 
+def _paged_kernel(table_ref, *refs, **kw):
+    # table_ref routed the k/v BlockSpecs; the body only needs the position.
+    del table_ref
+    _kernel(*refs, **kw)
+
+
 def decode_attention(q, k, v, pos, *, scale: float, window: int = 0,
                      cap: float = 0.0, kv_block: int = 512,
-                     interpret: bool = True):
+                     interpret: bool):
     """q (B,H,D), k/v (B,KV,S,D), pos (B,) -> (B,H,D)."""
     B, H, D = q.shape
     KV, S = k.shape[1], k.shape[2]
@@ -114,37 +108,41 @@ def decode_attention(q, k, v, pos, *, scale: float, window: int = 0,
         v = jnp.pad(v, ((0, 0), (0, 0), (0, k_pad), (0, 0)))
     nk = (S + k_pad) // tk
     q4 = q[:, :, None, :]  # (B, H, 1, D)
-    pos2 = pos.reshape(B, 1).astype(jnp.int32)
 
     kernel = functools.partial(_kernel, scale=scale, cap=cap, window=window,
                                tk=tk, nk=nk)
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(B, H, nk),
         in_specs=[
-            pl.BlockSpec((1, 1), lambda b, h, ki: (b, 0)),
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, ki: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, tk, D), lambda b, h, ki, g=G: (b, h // g, ki, 0)),
-            pl.BlockSpec((1, 1, tk, D), lambda b, h, ki, g=G: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, 1, D), lambda b, h, ki, p: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, tk, D),
+                         lambda b, h, ki, p, g=G: (b, h // g, ki, 0)),
+            pl.BlockSpec((1, 1, tk, D),
+                         lambda b, h, ki, p, g=G: (b, h // g, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, ki: (b, h, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, 1, D), lambda b, h, ki, p: (b, h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, D), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
             pltpu.VMEM((1,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(pos2, q4, k, v)
+    )(jnp.asarray(pos, jnp.int32), q4, k, v)
     return out[:, :, 0, :]
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
                            scale: float, window: int = 0, cap: float = 0.0,
-                           interpret: bool = True):
+                           interpret: bool):
     """Flash-decoding over a paged KV cache.
 
     q (B,H,D); k_pool/v_pool (N,KV,bs,D) — N physical blocks of bs tokens;
@@ -186,7 +184,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, pos, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, 1, D), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -19,10 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
 NEG_INF = -2.0e38
 
 
@@ -80,7 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention(q, k, v, *, scale: float, window: int = 0,
                     cap: float = 0.0, q_block: int = 512, kv_block: int = 512,
-                    interpret: bool = True):
+                    interpret: bool):
     """q (B,H,Sq,D), k/v (B,KV,Sk,D) -> (B,H,Sq,D). Causal."""
     B, H, Sq, D = q.shape
     KV, Sk = k.shape[1], k.shape[2]
@@ -115,7 +111,7 @@ def flash_attention(q, k, v, *, scale: float, window: int = 0,
             pltpu.VMEM((tq,), jnp.float32),
             pltpu.VMEM((tq,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,9 +1,11 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels run in ``interpret=True`` mode — the
-kernel body executes eagerly with the same block/grid schedule; on TPU the
-same call sites compile natively. Model code passes (B, S, H, D) layouts;
-these wrappers adapt to the kernels' (B, H, S, D).
+:func:`_interpret` is the one place that chooses by platform: on a TPU
+the kernels compile natively; on any other backend they run in Pallas
+interpret mode (the kernel body executes with the same block/grid
+schedule).  The kernels themselves take ``interpret`` without a default.
+Model code passes (B, S, H, D) layouts; these wrappers adapt to the
+kernels' (B, H, S, D).
 """
 from __future__ import annotations
 

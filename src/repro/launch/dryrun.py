@@ -32,7 +32,6 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from repro.compat import set_mesh
 from repro.obs.trace import monotonic
 
 
@@ -126,7 +125,7 @@ def build_step_and_args(cfg, shape, mesh, run, *, counting=False,
 
 def lower_compile(fn, args, mesh, donate=()):
     t0 = monotonic()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, donate_argnums=donate).lower(*args)
         t_lower = monotonic() - t0
         t0 = monotonic()
@@ -139,8 +138,6 @@ def analyze(compiled, mesh):
     from repro.launch import hlo as hlo_lib
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     out = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
@@ -175,10 +172,11 @@ def run_one(arch, shape_name, mesh_kind, outdir, skip_full=False,
     shape = get_shape(shape_name)
     cfg, variant = variant_config(cfg0, shape)
     if mesh_shape:  # §Perf lever: reinterpret the 256 chips, e.g. 32x8
-        import jax as _jax
+        from jax.sharding import AxisType
         dp_sz, tp_sz = mesh_shape
-        mesh = _jax.make_mesh((dp_sz, tp_sz), ("data", "model"),
-                              devices=_jax.devices()[: dp_sz * tp_sz])
+        mesh = jax.make_mesh((dp_sz, tp_sz), ("data", "model"),
+                             (AxisType.Auto,) * 2,
+                             devices=jax.devices()[: dp_sz * tp_sz])
     else:
         mesh = make_production_mesh(multi_pod=(mesh_kind == "multi"))
 

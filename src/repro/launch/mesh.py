@@ -8,7 +8,7 @@ from __future__ import annotations
 from typing import Dict, Optional, Tuple
 
 import jax
-from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
 
@@ -22,7 +22,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     devices = jax.devices()
     if len(devices) > n:  # e.g. 512 placeholder devices, single-pod mesh
         devices = devices[:n]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

@@ -1,7 +1,8 @@
 """Serving launcher — a thin CLI over the ``repro.api`` facade.
 
     PYTHONPATH=src python -m repro.launch.serve --arch gemma2-27b \
-        [--continuous | --static] [--requests 6] [--n-new 16] \
+        [--reduced | --full] [--continuous | --static] [--requests 6] \
+        [--n-new 16] \
         [--s-max 256] [--kv-block 16] [--max-kv-blocks 0] \
         [--prefill-chunk 0] [--arrival-trace poisson:0.5] \
         [--slo-ms 0] [--report-out PATH]
@@ -9,6 +10,8 @@
 Flags map onto a :class:`repro.api.JobSpec`; generation happens inside
 :meth:`repro.api.Session.serve` — continuous (in-flight batching over the
 paged KV cache, the default) or static (FIFO Engine/BatchScheduler).
+``--reduced`` (the default) serves the smoke-scale family member;
+``--full`` (or ``--no-reduced``) serves the published config.
 """
 from __future__ import annotations
 
@@ -17,11 +20,18 @@ import json
 from pathlib import Path
 
 from repro.api import JobSpec, Session
+from repro.launch.device import enable_compile_cache
 
 
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced family member (default); "
+                         "--full / --no-reduced for the full config")
+    ap.add_argument("--full", dest="reduced", action="store_false",
+                    help="alias for --no-reduced")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--n-new", type=int, default=16)
     ap.add_argument("--s-max", type=int, default=256)
@@ -56,7 +66,7 @@ def main():
                          "to this path")
     args = ap.parse_args()
 
-    spec = JobSpec(arch=args.arch, reduced=True, shape="decode_32k",
+    spec = JobSpec(arch=args.arch, reduced=args.reduced, shape="decode_32k",
                    requests=args.requests, n_new=args.n_new,
                    s_max=args.s_max, max_batch=args.max_batch,
                    serve_mode=args.mode, kv_block=args.kv_block,
@@ -64,6 +74,7 @@ def main():
                    prefill_chunk=args.prefill_chunk,
                    arrival=args.arrival_trace, slo_ms=args.slo_ms,
                    trace_dir=args.trace_dir)
+    enable_compile_cache()
     rep = Session(spec).serve()
     m = rep.measured
     for r in m["per_request"]:

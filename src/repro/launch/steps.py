@@ -55,6 +55,11 @@ def build_grad_fn(cfg: ModelConfig, run: RunConfig):
             def acc_body(carry, mb):
                 gsum, lsum = carry
                 (loss, _), g = grad_fn(params, mb)
+                # accumulate g as computed on its own: without the barrier
+                # XLA fuses the add into the backward pass and rounds
+                # differently from the 1F1B trainer, which adds each
+                # microbatch's gradient in a program of its own
+                g = jax.lax.optimization_barrier(g)
                 gsum = jax.tree_util.tree_map(jnp.add, gsum, g)
                 return (gsum, lsum + loss), None
 

@@ -7,13 +7,13 @@
 
 Flags map 1:1 onto a :class:`repro.api.JobSpec`; the actual procedure
 (planner resolution, strategy sizing, the loop) lives in
-:class:`repro.api.Session`.  On this CPU container ``--reduced`` (the
-smoke-scale family member, the default) is the realistic setting; disable it
-with ``--full`` (or ``--no-reduced``).  With ``--plan`` the session adopts
+:class:`repro.api.Session`.  ``--reduced`` (the smoke-scale family member,
+the default) is the setting for a CPU; ``--full`` (or ``--no-reduced``)
+trains the published config, which needs an accelerator.  With ``--plan`` the session adopts
 the planner's runtime knobs (microbatch / attention impl / remat /
 optimizer).  ``--dp N`` switches to the explicit data-parallel trainer: set
-``XLA_FLAGS=--xla_force_host_platform_device_count=N`` so the data axis has
-real (simulated) devices; ``--sync auto`` resolves the planner's
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` on the CPU so the
+data axis has simulated devices; ``--sync auto`` resolves the planner's
 ``Plan.sync_schedule`` to a runnable strategy.  ``--autotune`` runs the
 closed-loop autotuner first (``Session.tune``: measured kernel-variant
 choice + hardware calibration, see ``docs/tuning_guide.md``) and adopts its
@@ -28,6 +28,7 @@ from pathlib import Path
 import numpy as np
 
 from repro.api import JobSpec, Session
+from repro.launch.device import enable_compile_cache
 
 
 def build_spec(args) -> JobSpec:
@@ -118,6 +119,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     sess = Session(build_spec(args))
     if args.plan:
         print("planner:", sess.resolved_plan)

@@ -174,7 +174,8 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
 def attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
               impl="auto", kv_block=1024):
     if impl == "pallas":
-        # TPU production path; falls back to chunked under jit on CPU.
+        # the Pallas flash kernel: compiled on a TPU, run by the Pallas
+        # interpreter on any other backend (kernels/ops.py decides)
         from repro.kernels import ops as kops
         return kops.flash_attention(q, k, v, q_pos, k_pos, scale=scale,
                                     window=window, cap=cap)

@@ -167,7 +167,6 @@ def moe_mlp_sharded(p, x, cfg: ModelConfig, *, mesh, axis: str = "model",
     exactly 2 collectives per MoE layer instead of GSPMD's emergent storm.
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
 
     B, S, D = x.shape
     tp = mesh.shape[axis]
@@ -193,11 +192,11 @@ def moe_mlp_sharded(p, x, cfg: ModelConfig, *, mesh, axis: str = "model",
             aux = jax.lax.pmean(aux, a)
         return out, aux
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, axis, None), P(), P(axis, None, None),
                   P(axis, None, None), P(axis, None, None)),
-        out_specs=(P(dp, axis, None), P()),
+        out_specs=(P(dp, axis, None), P()), check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if cfg.num_shared_experts:
         out = out + dense_mlp(p["shared"], x)

@@ -38,6 +38,7 @@ class TrainResult:
     step_times: List[StepTimes]
     tokens_per_s: float
     start_step: int = 0
+    params: Any = None  # the final parameters, on the device
 
     @property
     def mean_r_o(self) -> float:
@@ -177,4 +178,5 @@ def train(cfg: ModelConfig, run: RunConfig, opt: opt_lib.OptConfig, *,
             mgr.close()
     wall = monotonic() - t_start
     tokens = (steps - start_step) * batch * seq
-    return TrainResult(losses, times, tokens / max(wall, 1e-9), start_step)
+    return TrainResult(losses, times, tokens / max(wall, 1e-9), start_step,
+                       params)

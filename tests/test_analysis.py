@@ -60,6 +60,18 @@ def test_kc103_sublane_misalignment():
     assert codes(kernel_contracts.check_contract(c)) == ["KC103"]
 
 
+@pytest.mark.parametrize("block", [
+    # the TPU compiler refused both: a second-minor 1 over a longer dim
+    kernel_contracts.Block("pos", (1, 1), 4, "in", (4, 1)),
+    kernel_contracts.Block("dt", (1, 1, 256), 4, "in", (4, 48, 4096)),
+], ids=["decode_pos", "ssd_dt"])
+def test_kc103_unit_sublane_over_a_longer_dim(block):
+    c = kernel_contracts.KernelContract(
+        op="decode_attention", context="fixture", grid=(4,),
+        blocks=(block,))
+    assert codes(kernel_contracts.check_contract(c)) == ["KC103"]
+
+
 def test_kc104_ssd_chunk_contract():
     c, findings = kernel_contracts.ssd_contract(
         B=1, H=4, L=100, P=64, N=128, chunk=64, context="fixture")

@@ -261,3 +261,30 @@ def test_session_tune_acceptance(tmp_path):
     run, _ = sess.build_run_opt()
     assert run.attn_impl == ("dense" if t["kernels"]["flash_attention"]
                              ["chosen"] == "ref" else "auto")
+
+
+@pytest.mark.parametrize("backend", ["cpu", "tpu"])
+def test_bench_kernels_reraises_pallas_failure_on_tpu(monkeypatch, backend):
+    """A failing variant is an infeasible algorithm on the CPU; on a TPU a
+    Pallas failure is a broken kernel and must not hand the op to the
+    reference."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import autotune
+    from repro.kernels import ops
+
+    def broken(*a):
+        raise ValueError("block shape refused")
+
+    monkeypatch.setattr(ops, "TUNABLE_OPS", ("flash_attention",))
+    monkeypatch.setattr(ops, "tune_inputs", lambda op, seq: (jnp.ones(4),))
+    monkeypatch.setattr(ops, "tune_candidates", lambda op, ssd_chunks: {
+        "pallas": broken, "ref": lambda x: x * 2})
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if backend == "tpu":
+        with pytest.raises(ValueError, match="refused"):
+            autotune.bench_kernels()
+    else:
+        out = autotune.bench_kernels()["flash_attention"]
+        assert out["chosen"] == "ref" and "pallas" in out["errors"]

@@ -11,6 +11,7 @@ trainer-level tests check the two contracts the subsystem ships:
 - a killed run resumed from its checkpoint onto a *different* ``(dp,
   pipe)`` grid reproduces the uninterrupted loss trajectory to 1e-6.
 """
+import dataclasses
 import json
 import os
 
@@ -330,8 +331,11 @@ def test_kill_and_resume_pipe2_to_dp(tmp_path, multi_device):
     run, opt = run_opt()
     kw = dict(batch=4, seq=16, seed=0, log_every=0)
     ck = str(tmp_path / "ck")
+    # the dp twin of pipe=2 x data=2 with 2 microbatches accumulates the
+    # same 1-row passes per device (batch 4 / dp 2 / 2 microbatches)
+    dp_run = dataclasses.replace(run, microbatch=1)
 
-    ref = DataParallelTrainer(cfg, run, opt, strategy="all_reduce",
+    ref = DataParallelTrainer(cfg, dp_run, opt, strategy="all_reduce",
                               devices=multi_device[:2])
     losses_ref = ref.train(steps=4, **kw).losses
 
@@ -340,7 +344,7 @@ def test_kill_and_resume_pipe2_to_dp(tmp_path, multi_device):
     rp = pipe.train(steps=2, ckpt_dir=ck, ckpt_every=2, **kw)
     np.testing.assert_allclose(rp.losses, losses_ref[:2], atol=1e-6)
 
-    resumed = DataParallelTrainer(cfg, run, opt, strategy="all_reduce",
+    resumed = DataParallelTrainer(cfg, dp_run, opt, strategy="all_reduce",
                                   devices=multi_device[:2])
     r2 = resumed.train(steps=4, ckpt_dir=ck, ckpt_every=2, **kw)
     assert r2.start_step == 2

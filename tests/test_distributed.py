@@ -7,12 +7,12 @@ from conftest import run_sub
 def test_moe_sharded_matches_baseline():
     run_sub("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs.base import get_config
     from repro.models import moe
     from repro.models.common import materialize
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
     cfg = get_config("jamba-1.5-large-398b").reduced().replace(
         num_experts=8, top_k=2, moe_d_ff=64, d_model=64)
     specs = moe.moe_specs(cfg, 1)
@@ -40,7 +40,7 @@ def test_moe_sharded_matches_baseline():
 def test_sharded_train_step_matches_single_device():
     run_sub("""
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs.base import get_config
     from repro.launch import mesh as mesh_lib
     from repro.launch.steps import build_train_step
@@ -62,7 +62,7 @@ def test_sharded_train_step_matches_single_device():
     p1, s1, m1 = jax.jit(build_train_step(cfg, run, opt))(params, state, batch)
 
     # sharded on a (2,4) mesh with the production rules + seq parallel
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), (AxisType.Auto,) * 2)
     rules = mesh_lib.sharding_rules(mesh, cfg, None, fsdp=True)
     pspecs = partition_specs(M.model_specs(cfg), rules)
     params_s = jax.tree_util.tree_map(
@@ -78,8 +78,7 @@ def test_sharded_train_step_matches_single_device():
                for k, v in batch.items()}
     run_s = RunConfig(attn_impl="dense", remat="none",
                       act_sharding=NamedSharding(mesh, P(("data",), "model", None)))
-    from repro.compat import set_mesh
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         p2, s2, m2 = jax.jit(build_train_step(cfg, run_s, opt))(
             params_s, state_s, batch_s)
 
@@ -97,10 +96,10 @@ def test_sharded_train_step_matches_single_device():
 def test_hlo_collective_accounting_known_program():
     run_sub("""
     import jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.launch import hlo
 
-    mesh = jax.make_mesh((4,), ("x",))
+    mesh = jax.make_mesh((4,), ("x",), (AxisType.Auto,))
 
     def f(a):  # force an all-reduce of a (256, 256) f32 = 256 KiB operand
         return jnp.sum(a * a)
@@ -121,6 +120,7 @@ def test_dryrun_single_combo_small_mesh():
     """End-to-end dryrun machinery on a small mesh (reduced arch)."""
     run_sub("""
     import jax, json
+    from jax.sharding import AxisType
     from repro.configs.base import get_config, get_shape, ShapeConfig
     from repro.launch import dryrun as D
     from repro.launch import mesh as mesh_lib
@@ -128,7 +128,7 @@ def test_dryrun_single_combo_small_mesh():
 
     # monkeypatch a small production mesh
     ml.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
-        (2, 4), ("data", "model"))
+        (2, 4), ("data", "model"), (AxisType.Auto,) * 2)
 
     cfg = get_config("granite-3-2b").reduced()
     import repro.configs.base as base

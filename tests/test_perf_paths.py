@@ -56,8 +56,8 @@ def test_logit_sharding_noop_on_single_device():
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
     run_a = RunConfig(attn_impl="dense", remat="none")
     la, _, _ = M.forward(params, {"tokens": toks}, cfg, run_a)
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
+    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
     run_b = RunConfig(attn_impl="dense", remat="none",
                       logit_sharding=NamedSharding(mesh, P(None, None, None)))
     lb, _, _ = M.forward(params, {"tokens": toks}, cfg, run_b)

@@ -198,7 +198,6 @@ def test_strategy_sync_means_match_global_mean(multi_device):
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.distributed.collectives import STRATEGIES, get_strategy
 
     dp = 8
@@ -224,8 +223,9 @@ def test_strategy_sync_means_match_global_mean(multi_device):
             local = jax.tree_util.tree_map(lambda x: x[0], stack)
             return strat.sync(local, "data", dp)
 
-        got = jax.jit(shard_map(
-            sync_one, mesh=mesh, in_specs=(P("data"),), out_specs=P()))(gstack)
+        got = jax.jit(jax.shard_map(
+            sync_one, mesh=mesh, in_specs=(P("data"),), out_specs=P(),
+            check_vma=False))(gstack)
         for w, g in zip(jax.tree_util.tree_leaves(want),
                         jax.tree_util.tree_leaves(got)):
             np.testing.assert_allclose(w, np.asarray(g), rtol=1e-6, atol=1e-7)
@@ -241,7 +241,6 @@ def test_hier_all_reduce_mean_on_2x4_topology(multi_device):
     import numpy as np
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from repro.compat import shard_map
     from repro.distributed.collectives import get_strategy
 
     dp = 8
@@ -264,9 +263,9 @@ def test_hier_all_reduce_mean_on_2x4_topology(multi_device):
             local = jax.tree_util.tree_map(lambda x: x[0], stack)
             return strat.sync(local, ("nodes", "data"), dp)
 
-        got = jax.jit(shard_map(
+        got = jax.jit(jax.shard_map(
             sync_one, mesh=mesh, in_specs=(P(("nodes", "data")),),
-            out_specs=P()))(gstack)
+            out_specs=P(), check_vma=False))(gstack)
         for w, g in zip(jax.tree_util.tree_leaves(want),
                         jax.tree_util.tree_leaves(got)):
             np.testing.assert_allclose(w, np.asarray(g), rtol=1e-6, atol=1e-7)
