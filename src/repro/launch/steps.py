@@ -15,6 +15,7 @@ from repro.launch import mesh as mesh_lib
 from repro.models import model as M
 from repro.models.blocks import RunConfig
 from repro.models.common import abstractify
+from repro.obs.scopes import scope
 from repro.optim import adamw as opt_lib
 
 
@@ -30,17 +31,19 @@ def build_grad_fn(cfg: ModelConfig, run: RunConfig):
     trainer (repro.distributed.trainer), which calls it per device shard
     inside shard_map."""
 
-    if run.bf16_grads:
-        # mixed precision: differentiate wrt the bf16 compute params so the
-        # data-axis gradient sync moves half the wire bytes; the optimizer
-        # still applies them to the fp32 master (cast in apply_updates)
-        def _loss_bf16(p, b):
-            return M.loss_fn(M.cast_params(p, cfg), b, cfg, run)
-        grad_fn = jax.value_and_grad(_loss_bf16, has_aux=True)
-    else:
-        grad_fn = jax.value_and_grad(
-            lambda p, b: M.loss_fn(p, b, cfg, run), has_aux=True
-        )
+    def loss(p, b):
+        # inside the differentiated function, so the backward pass shows
+        # as transpose(jvp(forward))/... in the ops' op_name
+        with scope("forward"):
+            if run.bf16_grads:
+                # mixed precision: differentiate wrt the bf16 compute params
+                # so the data-axis gradient sync moves half the wire bytes;
+                # the optimizer still applies them to the fp32 master (cast
+                # in apply_updates)
+                p = M.cast_params(p, cfg)
+            return M.loss_fn(p, b, cfg, run)
+
+    grad_fn = jax.value_and_grad(loss, has_aux=True)
 
     def grads_of(params, batch):
         if run.microbatch:
@@ -95,8 +98,9 @@ def build_train_step(cfg: ModelConfig, run: RunConfig, opt: opt_lib.OptConfig,
             # instead of an all-reduce (2x wire)
             grads = jax.tree_util.tree_map(
                 jax.lax.with_sharding_constraint, grads, run.grad_shardings)
-        new_params, new_state, gnorm = opt_lib.apply_updates(
-            opt, params, grads, opt_state)
+        with scope("optimizer"):
+            new_params, new_state, gnorm = opt_lib.apply_updates(
+                opt, params, grads, opt_state)
         out_metrics = {"loss": loss, "grad_norm": gnorm}
         out_metrics.update({k: v for k, v in (metrics or {}).items()})
         return new_params, new_state, out_metrics

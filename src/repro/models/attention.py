@@ -18,6 +18,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec, rope, softcap
+from repro.obs.scopes import scope
 
 NEG_INF = -2.0e38
 
@@ -173,22 +174,25 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
 
 def attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
               impl="auto", kv_block=1024):
-    if impl == "pallas":
-        # the Pallas flash kernel: compiled on a TPU, run by the Pallas
-        # interpreter on any other backend (kernels/ops.py decides)
-        from repro.kernels import ops as kops
-        return kops.flash_attention(q, k, v, q_pos, k_pos, scale=scale,
-                                    window=window, cap=cap)
-    if impl == "counting":
-        # dry-run FLOP-accounting mode: big unrolled blocks, no while loops
-        return chunked_attention(q, k, v, q_pos, k_pos, scale=scale,
-                                 window=window, cap=cap, kv_block=8192,
-                                 q_block=8192, unroll_kv=True)
-    if impl == "auto":
-        impl = "chunked" if k.shape[1] > 2048 else "dense"
-    f = dense_attention if impl == "dense" else chunked_attention
-    kw = {} if impl == "dense" else {"kv_block": kv_block}
-    return f(q, k, v, q_pos, k_pos, scale=scale, window=window, cap=cap, **kw)
+    with scope("attention_core"):
+        if impl == "pallas":
+            # the Pallas flash kernel: compiled on a TPU, run by the Pallas
+            # interpreter on any other backend (kernels/ops.py decides)
+            from repro.kernels import ops as kops
+            return kops.flash_attention(q, k, v, q_pos, k_pos, scale=scale,
+                                        window=window, cap=cap)
+        if impl == "counting":
+            # dry-run FLOP-accounting mode: big unrolled blocks, no while
+            # loops
+            return chunked_attention(q, k, v, q_pos, k_pos, scale=scale,
+                                     window=window, cap=cap, kv_block=8192,
+                                     q_block=8192, unroll_kv=True)
+        if impl == "auto":
+            impl = "chunked" if k.shape[1] > 2048 else "dense"
+        f = dense_attention if impl == "dense" else chunked_attention
+        kw = {} if impl == "dense" else {"kv_block": kv_block}
+        return f(q, k, v, q_pos, k_pos, scale=scale, window=window, cap=cap,
+                 **kw)
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,7 @@ from repro.models import attention as attn
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.common import ParamSpec, rms_norm
+from repro.obs.scopes import scope
 
 
 @dataclass
@@ -89,44 +90,50 @@ def slot_specs(cfg: ModelConfig, slot: SlotSpec, layers: int) -> Dict[str, Any]:
 
 def _mixer_forward(p, h, positions, cfg, slot: SlotSpec, run: RunConfig):
     if slot.mixer == "mamba":
-        return ssm_lib.ssm_forward(p, h, positions, cfg, impl="auto")
-    if slot.mixer.startswith("mla"):
-        return attn.mla_forward(p, h, positions, cfg, slot.mixer, impl=run.attn_impl)
-    return attn.gqa_forward(p, h, positions, cfg, slot.mixer, impl=run.attn_impl)
+        with scope("mamba"):
+            return ssm_lib.ssm_forward(p, h, positions, cfg, impl="auto")
+    with scope("attention"):
+        if slot.mixer.startswith("mla"):
+            return attn.mla_forward(p, h, positions, cfg, slot.mixer,
+                                    impl=run.attn_impl)
+        return attn.gqa_forward(p, h, positions, cfg, slot.mixer,
+                                impl=run.attn_impl)
 
 
 def _mlp_forward(p, h, cfg, slot: SlotSpec, run: RunConfig):
-    if slot.mlp == "dense":
-        return moe_lib.dense_mlp(p, h), 0.0
-    moe_fn = moe_lib.moe_mlp
-    kw = dict(capacity_factor=run.capacity_factor)
-    if run.moe_mesh is not None:
-        moe_fn = moe_lib.moe_mlp_sharded
-        kw.update(mesh=run.moe_mesh, axis=run.moe_axis)
-    if slot.mlp == "moe":
-        return moe_fn(p, h, cfg, **kw)
-    y_moe, aux = moe_fn(p["moe"], h, cfg, **kw)
-    return moe_lib.dense_mlp(p["dense"], h) + y_moe, aux
+    with scope("mlp"):
+        if slot.mlp == "dense":
+            return moe_lib.dense_mlp(p, h), 0.0
+        moe_fn = moe_lib.moe_mlp
+        kw = dict(capacity_factor=run.capacity_factor)
+        if run.moe_mesh is not None:
+            moe_fn = moe_lib.moe_mlp_sharded
+            kw.update(mesh=run.moe_mesh, axis=run.moe_axis)
+        if slot.mlp == "moe":
+            return moe_fn(p, h, cfg, **kw)
+        y_moe, aux = moe_fn(p["moe"], h, cfg, **kw)
+        return moe_lib.dense_mlp(p["dense"], h) + y_moe, aux
 
 
 def slot_forward(p, h, positions, cfg: ModelConfig, slot: SlotSpec, run: RunConfig):
     """Returns (h, cache, aux_loss)."""
-    resid = h
-    u = rms_norm(h, p["mixer_norm"], cfg.norm_eps)
-    u, cache = _mixer_forward(p["mixer"], u, positions, cfg, slot, run)
-    if cfg.use_post_norm:
-        u = rms_norm(u, p["mixer_post_norm"], cfg.norm_eps)
-    h = constrain(resid + u, run.act_sharding)
-
-    aux = 0.0
-    if "mlp_norm" in p:
+    with scope("block"):
         resid = h
-        u = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-        u, aux = _mlp_forward(p["mlp"], u, cfg, slot, run)
+        u = rms_norm(h, p["mixer_norm"], cfg.norm_eps)
+        u, cache = _mixer_forward(p["mixer"], u, positions, cfg, slot, run)
         if cfg.use_post_norm:
-            u = rms_norm(u, p["mlp_post_norm"], cfg.norm_eps)
+            u = rms_norm(u, p["mixer_post_norm"], cfg.norm_eps)
         h = constrain(resid + u, run.act_sharding)
-    return h, cache, aux
+
+        aux = 0.0
+        if "mlp_norm" in p:
+            resid = h
+            u = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
+            u, aux = _mlp_forward(p["mlp"], u, cfg, slot, run)
+            if cfg.use_post_norm:
+                u = rms_norm(u, p["mlp_post_norm"], cfg.norm_eps)
+            h = constrain(resid + u, run.act_sharding)
+        return h, cache, aux
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ from repro.models.blocks import (RunConfig, constrain, slot_cache_specs,
                                  slot_decode, slot_extend, slot_forward,
                                  slot_specs)
 from repro.models.common import (ParamSpec, cross_entropy, rms_norm, softcap)
+from repro.obs.scopes import scope
 
 
 # ---------------------------------------------------------------------------
@@ -80,21 +81,23 @@ def cache_specs(cfg: ModelConfig, batch: int, s_max: int,
 
 
 def embed_tokens(params, batch: Dict[str, jax.Array], cfg: ModelConfig):
-    tokens = batch["tokens"]
-    if cfg.num_codebooks:
-        # (B,S,K) -> sum_k embed_k[token]
-        parts = [
-            jnp.take(params["embed"][k], tokens[..., k], axis=0)
-            for k in range(cfg.num_codebooks)
-        ]
-        h = sum(parts)
-    else:
-        h = jnp.take(params["embed"], tokens, axis=0)
-    if "image_embeds" in batch:
-        h = jnp.concatenate([batch["image_embeds"].astype(h.dtype), h], axis=1)
-    if cfg.scale_embed:
-        h = h * np.sqrt(cfg.d_model)
-    return h.astype(jnp.dtype(cfg.dtype))
+    with scope("embed"):
+        tokens = batch["tokens"]
+        if cfg.num_codebooks:
+            # (B,S,K) -> sum_k embed_k[token]
+            parts = [
+                jnp.take(params["embed"][k], tokens[..., k], axis=0)
+                for k in range(cfg.num_codebooks)
+            ]
+            h = sum(parts)
+        else:
+            h = jnp.take(params["embed"], tokens, axis=0)
+        if "image_embeds" in batch:
+            h = jnp.concatenate([batch["image_embeds"].astype(h.dtype), h],
+                                axis=1)
+        if cfg.scale_embed:
+            h = h * np.sqrt(cfg.d_model)
+        return h.astype(jnp.dtype(cfg.dtype))
 
 
 def lm_logits(params, h, cfg: ModelConfig):
@@ -187,11 +190,13 @@ def forward(params, batch, cfg: ModelConfig, run: RunConfig,
         h, pre_caches = jax.lax.scan(pre_cycle, h, params["prelude"])
 
     h, caches, aux = _scan_cycles(params, h, positions, cfg, run, with_cache)
-    h = rms_norm(h, params["final_norm"], cfg.norm_eps)
-    logits = lm_logits(params, h, cfg)
-    # §Perf: keep logits sequence-sharded through the CE path (prevents a
-    # full-vocab unsharded materialization, ~40 GB f32 for qwen2-72b train)
-    logits = constrain(logits, run.logit_sharding)
+    with scope("head_loss"):
+        h = rms_norm(h, params["final_norm"], cfg.norm_eps)
+        logits = lm_logits(params, h, cfg)
+        # §Perf: keep logits sequence-sharded through the CE path (prevents
+        # a full-vocab unsharded materialization, ~40 GB f32 for qwen2-72b
+        # train)
+        logits = constrain(logits, run.logit_sharding)
     all_caches = {"slots": caches}
     if cfg.first_k_dense:
         all_caches["prelude"] = pre_caches
@@ -203,14 +208,15 @@ def loss_fn(params, batch, cfg: ModelConfig, run: RunConfig,
     """Masked next-token CE. ``labels`` < 0 are ignored. For VLM inputs the
     image-prefix positions carry no labels (mask handled via label padding)."""
     logits, _, aux = forward(params, batch, cfg, run)
-    labels = batch["labels"]
-    if "image_embeds" in batch:
-        n_img = batch["image_embeds"].shape[1]
-        pad = jnp.full(labels.shape[:1] + (n_img,) + labels.shape[2:], -1,
-                       labels.dtype)
-        labels = jnp.concatenate([pad, labels], axis=1)
-    mask = (labels >= 0).astype(jnp.float32)
-    ce = cross_entropy(logits, jnp.maximum(labels, 0), mask)
+    with scope("head_loss"):
+        labels = batch["labels"]
+        if "image_embeds" in batch:
+            n_img = batch["image_embeds"].shape[1]
+            pad = jnp.full(labels.shape[:1] + (n_img,) + labels.shape[2:],
+                           -1, labels.dtype)
+            labels = jnp.concatenate([pad, labels], axis=1)
+        mask = (labels >= 0).astype(jnp.float32)
+        ce = cross_entropy(logits, jnp.maximum(labels, 0), mask)
     return ce + aux_weight * aux, {"ce": ce, "aux": aux}
 
 

@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec, rms_norm, swish
+from repro.obs.scopes import scope
 
 
 def ssm_specs(cfg: ModelConfig, layers: int) -> Dict[str, ParamSpec]:
@@ -150,11 +151,14 @@ def ssm_forward(p, x, positions, cfg: ModelConfig, *, impl="auto"):
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"]).astype(x.dtype)
     a_neg = -jnp.exp(p["a_log"].astype(jnp.float32)).astype(x.dtype)
 
-    if impl == "pallas":
-        from repro.kernels import ops as kops
-        y, h_fin = kops.ssd_scan(xs, dt, a_neg, b_mat, c_mat, chunk=cfg.ssm_chunk)
-    else:
-        y, h_fin = ssd_chunked(xs, dt, a_neg, b_mat, c_mat, cfg.ssm_chunk)
+    with scope("ssd_scan"):
+        if impl == "pallas":
+            from repro.kernels import ops as kops
+            y, h_fin = kops.ssd_scan(xs, dt, a_neg, b_mat, c_mat,
+                                     chunk=cfg.ssm_chunk)
+        else:
+            y, h_fin = ssd_chunked(xs, dt, a_neg, b_mat, c_mat,
+                                   cfg.ssm_chunk)
     y = y + xs * p["d_skip"][None, None, :, None]
     y = y.reshape(B, L, DI)
     y = rms_norm(y * swish(z), p["gate_norm"], cfg.norm_eps)

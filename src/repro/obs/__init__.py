@@ -5,8 +5,9 @@ measuring half every subsystem reports through:
 
 - :class:`~repro.obs.trace.Tracer` / :class:`~repro.obs.trace.Span` —
   nestable phase-level wall-clock spans, Chrome-trace/Perfetto export,
-  optional ``jax.profiler`` annotation bracketing, and a zero-cost
-  disabled fast path.
+  and a zero-cost disabled fast path.
+- :data:`~repro.obs.scopes.COMPONENTS` — the ``jax.named_scope`` names
+  that label a step's device work in a profiler trace.
 - :class:`~repro.obs.metrics.MetricsRegistry` — counters, gauges, and
   p50/p95/p99 histograms; renders the ``repro.api/metrics/v1`` section
   that every measured ``Report`` carries (``validate_metrics`` is the

@@ -17,13 +17,12 @@ exit.  ``Tracer`` is the one clock those paths share:
 - A *disabled* tracer is free: ``span()`` returns a shared no-op singleton
   (no event, no allocation that survives the call), so library code can
   trace unconditionally.
-- ``jax_annotations=True`` additionally brackets every span with
-  ``jax.profiler.TraceAnnotation`` so a device-side profile collected with
-  ``jax.profiler.trace()`` carries the same phase names.
 
-Import-light by design (stdlib only unless annotations are enabled): the
-rest of ``repro.obs`` must be usable from ``repro.core``/CLI tools without
-pulling in a backend.
+Spans are host-only.  The device work of a step is named by the scopes of
+:mod:`repro.obs.scopes`, which a profiler trace carries on every op.
+
+Import-light by design (stdlib only): the rest of ``repro.obs`` must be
+usable from ``repro.core``/CLI tools without pulling in a backend.
 """
 from __future__ import annotations
 
@@ -82,7 +81,7 @@ class Span:
     """A live span; use as a context manager.  ``elapsed_s`` after exit is
     the phase wall clock (mid-flight it reads the running elapsed)."""
 
-    __slots__ = ("tracer", "name", "args", "t0", "t1", "depth", "_ann")
+    __slots__ = ("tracer", "name", "args", "t0", "t1", "depth")
 
     def __init__(self, tracer: "Tracer", name: str,
                  args: Optional[Dict[str, Any]]):
@@ -92,7 +91,6 @@ class Span:
         self.t0 = 0.0
         self.t1 = 0.0
         self.depth = 0
-        self._ann = None
 
     @property
     def elapsed_s(self) -> float:
@@ -105,18 +103,11 @@ class Span:
         stack = tr._thread_stack()
         self.depth = len(stack)
         stack.append(self.name)
-        if tr.jax_annotations:
-            self._ann = tr._annotation(self.name)
-            if self._ann is not None:
-                self._ann.__enter__()
-        self.t0 = tr._clock()  # last: annotation setup stays untimed
+        self.t0 = tr._clock()  # last: bookkeeping stays untimed
         return self
 
     def __exit__(self, *exc) -> bool:
         self.t1 = self.tracer._clock()  # first: recording stays untimed
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
         self.tracer._record(self)
         return False
 
@@ -130,10 +121,9 @@ class Tracer:
     """
 
     def __init__(self, enabled: bool = True, *, max_events: int = 100_000,
-                 jax_annotations: bool = False, clock=time.perf_counter):
+                 clock=time.perf_counter):
         self._enabled = bool(enabled)
         self.max_events = int(max_events)
-        self.jax_annotations = bool(jax_annotations)
         self._clock = clock
         self._epoch = clock()
         self._events: List[SpanEvent] = []
@@ -158,14 +148,6 @@ class Tracer:
         if stack is None:
             stack = self._local.stack = []
         return stack
-
-    @staticmethod
-    def _annotation(name: str):
-        try:
-            from jax.profiler import TraceAnnotation
-        except Exception:  # no backend: annotations silently off
-            return None
-        return TraceAnnotation(name)
 
     def _record(self, span: Span) -> None:
         stack = self._thread_stack()

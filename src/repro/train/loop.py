@@ -34,6 +34,11 @@ from repro.checkpoint import CheckpointManager, latest_step as ckpt_latest
 
 @dataclass
 class TrainResult:
+    """``tokens_per_s`` is timed from the end of the first step to the end
+    of the last, over the tokens of the steps after the first, so the first
+    step's compile is not in it; with a single step it is that step's
+    tokens over its whole wall clock, compile included."""
+
     losses: List[float]
     step_times: List[StepTimes]
     tokens_per_s: float
@@ -144,6 +149,7 @@ def train(cfg: ModelConfig, run: RunConfig, opt: opt_lib.OptConfig, *,
     losses: List[float] = []
     times: List[StepTimes] = []
     t_start = monotonic()
+    t_first = None  # end of the first step: the throughput clock starts
     try:
         for i in range(start_step, steps):
             with tracer.span("data_wait", step=i):
@@ -171,12 +177,17 @@ def train(cfg: ModelConfig, run: RunConfig, opt: opt_lib.OptConfig, *,
                       f"compute {t_comp*1e3:.0f}ms io "
                       f"{(bt.data_load+bt.data_prep+bt.h2d)*1e3:.0f}ms",
                       flush=True)
+            if t_first is None:
+                t_first = monotonic()
     finally:
         if own_loader:
             loader.close()
         if mgr is not None:
             mgr.close()
-    wall = monotonic() - t_start
-    tokens = (steps - start_step) * batch * seq
-    return TrainResult(losses, times, tokens / max(wall, 1e-9), start_step,
-                       params)
+    t_end = monotonic()
+    n = steps - start_step
+    if n > 1:
+        tps = (n - 1) * batch * seq / max(t_end - t_first, 1e-9)
+    else:
+        tps = n * batch * seq / max(t_end - t_start, 1e-9)
+    return TrainResult(losses, times, tps, start_step, params)

@@ -163,6 +163,24 @@ def test_clear_resets_epoch_and_events():
 # ---------------------------------------------------------------------------
 
 
+def test_scope_names_a_component_and_refuses_others():
+    import jax
+    import jax.numpy as jnp
+
+    from repro.obs.scopes import COMPONENTS, scope
+
+    assert len(set(COMPONENTS)) == len(COMPONENTS)
+    with pytest.raises(ValueError, match="unknown scope"):
+        scope("backward")
+
+    def f(x):
+        with scope("mlp"):
+            return jnp.sin(x)
+
+    hlo = jax.jit(f).lower(jnp.ones(4)).as_text(debug_info=True)
+    assert "mlp/sin" in hlo
+
+
 def test_percentile_matches_numpy():
     rng = np.random.default_rng(0)
     for values in (rng.normal(10, 3, 257), rng.exponential(1.0, 100),

@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.configs.base import get_config
 from repro.data.pipeline import PrefetchLoader, SyntheticCorpus
@@ -90,6 +91,33 @@ def test_train_loop_loss_decreases():
     assert last < first - 0.25, (first, last)
     assert res.tokens_per_s > 0
     assert 0 <= res.mean_r_o < 10
+
+
+@pytest.mark.parametrize("steps, expected", [(4, 8 * 32 / 2.0),
+                                              (1, 8 * 32 / 100.0)])
+def test_train_tokens_per_s_leaves_out_the_first_step(monkeypatch, steps,
+                                                      expected):
+    """The first step takes 100 s (its compile) and each later one 2 s on
+    the loop's clock: the throughput counts the steps after the first; a
+    single step keeps its whole wall clock."""
+    import repro.train.loop as loop_mod
+
+    now = [0.0]
+    monkeypatch.setattr(loop_mod, "monotonic", lambda: now[0])
+    calls = []
+
+    def step_fn(params, opt_state, batch):
+        now[0] += 100.0 if not calls else 2.0
+        calls.append(1)
+        return params, opt_state, {"loss": jnp.float32(1.0)}
+
+    cfg = tiny_cfg()
+    opt = opt_lib.OptConfig(lr=1e-3)
+    res = train(cfg, RunConfig(), opt, batch=8, seq=32, steps=steps,
+                log_every=0, params={"w": jnp.zeros(2)},
+                opt_state={"step": 0}, step_fn=step_fn)
+    assert len(res.losses) == steps
+    assert res.tokens_per_s == pytest.approx(expected)
 
 
 def test_train_microbatch_equivalent_shapes():
