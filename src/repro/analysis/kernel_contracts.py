@@ -101,33 +101,101 @@ def _finding(op: str, code: str, msg: str, context: str) -> Finding:
 # ---------------------------------------------------------------------------
 
 
+def _gqa_finding(op: str, H: int, KV: int, context: str) -> List[Finding]:
+    if KV <= 0 or H % KV:
+        return [_finding(op, "KC106",
+                         f"H={H} not divisible by KV={KV}; the "
+                         "h // (H // KV) GQA index map is undefined",
+                         context)]
+    return []
+
+
+def _flash_geometry(Sq: int, Sk: int, q_block: int, kv_block: int):
+    """Blocks clamped to the sequences and the padded lengths, as
+    ``kernels.flash_attention.flash_attention`` pads for given blocks."""
+    tq = min(q_block, max(Sq, 8))
+    tk = min(kv_block, max(Sk, 8))
+    return tq, tk, Sq + (-Sq % tq), Sk + (-Sk % tk)
+
+
 def flash_contract(*, B: int, H: int, KV: int, Sq: int, Sk: int, D: int,
                    dtype_bytes: int = 2, q_block: int = 512,
                    kv_block: int = 512, context: str = "flash_attention",
                    ) -> Tuple[Optional[KernelContract], List[Finding]]:
-    """Mirror of ``kernels.flash_attention``: tq/tk clamped to the padded
-    sequence, grid (B, H, nq, nk), f32 accumulator + running max/sum."""
+    """Mirror of the forward kernel of ``kernels.flash_attention``: q, k, v
+    and o as (B, heads, D, S) with the sequence in the lanes, tq/tk clamped
+    to the padded sequence, grid (B, H, nq, nk); q as (tq, D) rows, f32
+    accumulator and lane-replicated running max/sum in scratch; the f32
+    log-sum-exp written as (B, H, 1, Sq) rows."""
     op = "flash_attention"
-    if KV <= 0 or H % KV:
-        return None, [_finding(op, "KC106",
-                               f"H={H} not divisible by KV={KV}; the "
-                               "h // (H // KV) GQA index map is undefined",
-                               context)]
-    tq = min(q_block, max(Sq, 8))
-    tk = min(kv_block, max(Sk, 8))
-    sq_p = Sq + (-Sq % tq)
-    sk_p = Sk + (-Sk % tk)
+    bad = _gqa_finding(op, H, KV, context)
+    if bad:
+        return None, bad
+    tq, tk, sq_p, sk_p = _flash_geometry(Sq, Sk, q_block, kv_block)
     grid = (B, H, sq_p // tq, sk_p // tk)
     blocks = (
-        Block("q", (1, 1, tq, D), dtype_bytes, "in", (B, H, sq_p, D)),
-        Block("k", (1, 1, tk, D), dtype_bytes, "in", (B, KV, sk_p, D)),
-        Block("v", (1, 1, tk, D), dtype_bytes, "in", (B, KV, sk_p, D)),
-        Block("out", (1, 1, tq, D), dtype_bytes, "out", (B, H, sq_p, D)),
+        Block("q", (1, 1, D, tq), dtype_bytes, "in", (B, H, D, sq_p)),
+        Block("k", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
+        Block("v", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
+        Block("out", (1, 1, D, tq), dtype_bytes, "out", (B, H, D, sq_p)),
+        Block("lse", (1, 1, 1, tq), 4, "out", (B, H, 1, sq_p)),
+        Block("q_rows", (tq, D), dtype_bytes, "scratch"),
         Block("acc", (tq, D), 4, "scratch"),
-        Block("m_run", (tq,), 4, "scratch"),
-        Block("l_run", (tq,), 4, "scratch"),
+        Block("m_run", (tq, 128), 4, "scratch"),
+        Block("l_run", (tq, 128), 4, "scratch"),
     )
     return KernelContract(op, context, grid, blocks), []
+
+
+def flash_bwd_contracts(*, B: int, H: int, KV: int, Sq: int, Sk: int,
+                        D: int, dtype_bytes: int = 2, q_block: int = 512,
+                        kv_block: int = 512, context: str = "flash_attention",
+                        ) -> Tuple[List[KernelContract], List[Finding]]:
+    """Mirrors of the two backward kernels: dK/dV on grid (B, KV, nk, G,
+    nq) with k and v as (tk, D) rows and (tk, D) f32 dK and dV
+    accumulators in scratch, and dQ on grid (B, H, nq, nk) with q and dO
+    as (tq, D) rows and a (tq, D) f32 accumulator.  Both read (B, heads,
+    D, S) blocks of q, dO, k, v and the f32 log-sum-exp and
+    ``rowsum(dO * O)`` as (1, tq) rows.  Their contexts are ``context``
+    with ``.dkv`` / ``.dq`` after the op."""
+    op = "flash_attention"
+    bad = _gqa_finding(op, H, KV, context)
+    if bad:
+        return [], bad
+    tq, tk, sq_p, sk_p = _flash_geometry(Sq, Sk, q_block, kv_block)
+    nq, nk = sq_p // tq, sk_p // tk
+    head, sep, rest = context.partition(":")
+
+    def sub(kernel: str) -> str:
+        return f"{head}.{kernel}{sep}{rest}"
+
+    q_side = (
+        Block("q", (1, 1, D, tq), dtype_bytes, "in", (B, H, D, sq_p)),
+        Block("do", (1, 1, D, tq), dtype_bytes, "in", (B, H, D, sq_p)),
+        Block("lse", (1, 1, 1, tq), 4, "in", (B, H, 1, sq_p)),
+        Block("delta", (1, 1, 1, tq), 4, "in", (B, H, 1, sq_p)),
+    )
+    kv_side = (
+        Block("k", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
+        Block("v", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
+    )
+    dkv = KernelContract(
+        op, sub("dkv"), (B, KV, nk, H // KV, nq), q_side + kv_side + (
+            Block("dk", (1, 1, D, tk), dtype_bytes, "out", (B, KV, D, sk_p)),
+            Block("dv", (1, 1, D, tk), dtype_bytes, "out", (B, KV, D, sk_p)),
+            Block("k_rows", (tk, D), dtype_bytes, "scratch"),
+            Block("v_rows", (tk, D), dtype_bytes, "scratch"),
+            Block("dk_acc", (tk, D), 4, "scratch"),
+            Block("dv_acc", (tk, D), 4, "scratch"),
+        ))
+    dq = KernelContract(
+        op, sub("dq"), (B, H, nq, nk), q_side + kv_side + (
+            Block("dq", (1, 1, D, tq), dtype_bytes, "out", (B, H, D, sq_p)),
+            Block("q_rows", (tq, D), dtype_bytes, "scratch"),
+            Block("do_rows", (tq, D), dtype_bytes, "scratch"),
+            Block("dq_acc", (tq, D), 4, "scratch"),
+        ))
+    return [dkv, dq], []
 
 
 def decode_contract(*, B: int, H: int, KV: int, S: int, D: int,
@@ -288,6 +356,9 @@ def registry_contracts(
     findings, audit) where audit maps op -> the contexts it was checked
     under — the acceptance hook that every tunable op faces >= 2 configs.
     """
+    # the kernel's own block choice (imports jax, as the drift guard does)
+    from repro.kernels.flash_attention import block_sizes
+
     contracts: List[KernelContract] = []
     findings: List[Finding] = []
     audit: Dict[str, List[str]] = {}
@@ -314,11 +385,19 @@ def registry_contracts(
                 dec_kv, dec_d = KV, D
             for shape in ("train_4k", "prefill_32k"):
                 s = SHAPES[shape].seq_len
+                (tq, tk), (bq, bk) = block_sizes(s, s, D)
                 for db in dtypes:
                     ctx = f"flash_attention:{arch}:{shape}:{DTYPE_NAMES[db]}"
                     add("flash_attention",
                         flash_contract(B=batch, H=H, KV=KV, Sq=s, Sk=s,
-                                       D=D, dtype_bytes=db, context=ctx))
+                                       D=D, dtype_bytes=db, q_block=tq,
+                                       kv_block=tk, context=ctx))
+                    bwd, fs = flash_bwd_contracts(
+                        B=batch, H=H, KV=KV, Sq=s, Sk=s, D=D,
+                        dtype_bytes=db, q_block=bq, kv_block=bk,
+                        context=ctx)
+                    for c in bwd:
+                        add("flash_attention", (c, fs))
             for shape in ("decode_32k", "long_500k"):
                 s = SHAPES[shape].seq_len
                 for db in dtypes:

@@ -26,7 +26,8 @@ def _interpret() -> bool:
 @functools.partial(jax.jit, static_argnames=("scale", "window", "cap"))
 def flash_attention(q, k, v, q_pos=None, k_pos=None, *, scale, window=0,
                     cap=0.0):
-    """(B,S,H,D) x (B,S,KV,D) -> (B,S,H,D), causal from position 0."""
+    """(B,S,H,D) x (B,S,KV,D) -> (B,S,H,D), causal from position 0, with
+    its own backward kernels; the positions are not read."""
     out = fa_k.flash_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3),

@@ -1,9 +1,11 @@
 """Attention mixers: GQA (optionally sliding-window / softcapped) and MLA
 (DeepSeek-V2 multi-head latent attention), each with
 
-  * full-sequence path (train / prefill)  — ``dense`` or ``chunked`` impl
-    (chunked = online-softmax scan over KV blocks: the XLA flash-attention
-    reference; the Pallas kernel in ``repro.kernels`` mirrors its math), and
+  * full-sequence path (train / prefill)  — ``dense``, ``chunked`` or
+    ``pallas`` impl (chunked = online-softmax scan over KV blocks: the XLA
+    flash-attention reference; the Pallas kernel in ``repro.kernels``
+    mirrors its math and has its own backward pass; ``auto`` takes it on a
+    TPU), and
   * cached single-token decode path (MLA uses the absorbed-latent form).
 
 Shapes: x (B, S, D); caches are per-slot dicts of (B, S_max, ...) arrays.
@@ -18,6 +20,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec, rope, softcap
+from repro.obs.metrics import TRACE_COUNTS
 from repro.obs.scopes import scope
 
 NEG_INF = -2.0e38
@@ -172,9 +175,31 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
     return out[:, :Sq].astype(v.dtype)
 
 
+# the name each impl is counted under in ``TRACE_COUNTS``
+_COUNTED = {"pallas": "flash", "counting": "chunked"}
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def _auto_impl(q, k, v) -> str:
+    """``impl="auto"``: the Pallas flash kernel for full-sequence
+    self-attention on a TPU (the train and prefill paths, whose positions
+    are ``arange(S)``), else dense up to 2048 keys and chunked above."""
+    S = q.shape[1]
+    if (_on_tpu() and k.shape[1] == S and q.shape[-1] == v.shape[-1]
+            and S % 128 == 0):
+        return "pallas"
+    return "chunked" if k.shape[1] > 2048 else "dense"
+
+
 def attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
               impl="auto", kv_block=1024):
     with scope("attention_core"):
+        if impl == "auto":
+            impl = _auto_impl(q, k, v)
+        TRACE_COUNTS.inc("attention/" + _COUNTED.get(impl, impl))
         if impl == "pallas":
             # the Pallas flash kernel: compiled on a TPU, run by the Pallas
             # interpreter on any other backend (kernels/ops.py decides)
@@ -187,8 +212,6 @@ def attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
             return chunked_attention(q, k, v, q_pos, k_pos, scale=scale,
                                      window=window, cap=cap, kv_block=8192,
                                      q_block=8192, unroll_kv=True)
-        if impl == "auto":
-            impl = "chunked" if k.shape[1] > 2048 else "dense"
         f = dense_attention if impl == "dense" else chunked_attention
         kw = {} if impl == "dense" else {"kv_block": kv_block}
         return f(q, k, v, q_pos, k_pos, scale=scale, window=window, cap=cap,

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig, SlotSpec
+from repro.kernels import FLASH_RESIDUALS
 from repro.models.blocks import (RunConfig, constrain, slot_cache_specs,
                                  slot_decode, slot_extend, slot_forward,
                                  slot_specs)
@@ -137,7 +138,12 @@ def _scan_cycles(params, h, positions, cfg, run, with_cache: bool):
 
     body = cycle
     if run.remat != "none":
-        body = jax.checkpoint(cycle, prevent_cse=False)
+        # keep the flash kernel's output and log-sum-exp: the backward pass
+        # then recomputes the block without the attention forward
+        body = jax.checkpoint(
+            cycle, prevent_cse=False,
+            policy=jax.checkpoint_policies.save_only_these_names(
+                *FLASH_RESIDUALS))
 
     if run.unroll_layers:
         n = main_cycles(cfg)

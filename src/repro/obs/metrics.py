@@ -26,7 +26,8 @@ import random
 from typing import Any, Dict, List
 
 __all__ = ["METRICS_SCHEMA_ID", "Counter", "Gauge", "Histogram",
-           "MetricsRegistry", "percentile", "validate_metrics"]
+           "MetricsRegistry", "TRACE_COUNTS", "percentile",
+           "validate_metrics"]
 
 METRICS_SCHEMA_ID = "repro.api/metrics/v1"
 
@@ -178,6 +179,12 @@ class MetricsRegistry:
                            for n, h in sorted(self._histograms.items())
                            if h.count},
         }
+
+
+# What the program chose while it was traced (once per compile, not per
+# step): ``attention/flash``, ``attention/dense`` and ``attention/chunked``
+# count the implementations ``repro.models.attention.attention`` picked.
+TRACE_COUNTS = MetricsRegistry()
 
 
 # ---------------------------------------------------------------------------

@@ -99,6 +99,38 @@ def test_kc_known_good_contract_is_clean():
     assert kernel_contracts.check_contract(c) == []
 
 
+def test_kc_flash_backward_contracts():
+    # granite's training widths at the kernel's own blocks are clean
+    from repro.kernels.flash_attention import block_sizes
+
+    (tq, tk), (bq, bk) = block_sizes(2048, 2048, 64)
+    c, findings = kernel_contracts.flash_contract(
+        B=4, H=32, KV=8, Sq=2048, Sk=2048, D=64, q_block=tq, kv_block=tk,
+        context="fixture:granite")
+    assert not findings and kernel_contracts.check_contract(c) == []
+    bwd, findings = kernel_contracts.flash_bwd_contracts(
+        B=4, H=32, KV=8, Sq=2048, Sk=2048, D=64, q_block=bq, kv_block=bk,
+        context="fixture:granite")
+    assert not findings
+    assert [b.context for b in bwd] == ["fixture.dkv:granite",
+                                        "fixture.dq:granite"]
+    assert bwd[0].grid == (4, 8, 2048 // bk, 4, 2048 // bq)
+    assert bwd[1].grid == (4, 32, 2048 // bq, 2048 // bk)
+    assert all(kernel_contracts.check_contract(b) == [] for b in bwd)
+    # a q block of 24 positions is off the 128-wide lane for q, dO and the
+    # log-sum-exp rows
+    bwd, _ = kernel_contracts.flash_bwd_contracts(
+        B=1, H=8, KV=2, Sq=2048, Sk=2048, D=64, q_block=24, kv_block=512,
+        context="fixture")
+    for b in bwd:
+        assert "KC102" in codes(kernel_contracts.check_contract(b))
+
+    # and the GQA map is checked once for both
+    bwd, findings = kernel_contracts.flash_bwd_contracts(
+        B=1, H=7, KV=2, Sq=128, Sk=128, D=64, context="fixture")
+    assert bwd == [] and codes(findings) == ["KC106"]
+
+
 def test_kc_registry_clean_and_audited():
     findings, audit = kernel_contracts.check_registry()
     assert findings == [], [str(f) for f in findings]

@@ -223,3 +223,107 @@ def test_flash_matches_chunked_model_path():
         q_block=64).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize(
+    "B,S,H,KV,D,window,cap,qb,kb",
+    [
+        (1, 256, 4, 4, 64, 0, 0.0, 64, 128),     # MHA, uneven blocks
+        (1, 256, 8, 2, 64, 0, 0.0, 128, 64),     # H = 4 KV
+        (1, 256, 8, 2, 64, 64, 0.0, 64, 64),     # sliding window
+        (1, 192, 4, 4, 128, 0, 30.0, 64, 64),    # softcap, D = 128
+        (1, 192, 8, 2, 128, 48, 30.0, 64, 64),   # window + cap + GQA
+        (2, 96, 4, 2, 64, 0, 0.0, 64, 64),       # padded tail
+    ],
+)
+def test_flash_attention_grads_match_oracle(B, S, H, KV, D, window, cap, qb,
+                                            kb, dtype):
+    """The custom VJP (dK/dV and dQ kernels) against jax.grad of the dense
+    oracle: output, dq, dk and dv, over several q and k blocks."""
+    q, k, v = _mk_qkv(jax.random.PRNGKey(6), B, S, S, H, KV, D, dtype)
+    w = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+    scale = 1.0 / np.sqrt(D)
+
+    def loss(attn):
+        def f(q, k, v):
+            o = attn(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (dq, dk, dv), out = loss(lambda q, k, v: flash_attention(
+        q, k, v, scale=scale, window=window, cap=cap, q_block=qb,
+        kv_block=kb, interpret=True))(q, k, v)
+    (rq, rk, rv), want = loss(lambda q, k, v: ref.flash_attention_ref(
+        q, k, v, scale=scale, window=window, cap=cap))(q, k, v)
+    for got, exp in ((out, want), (dq, rq), (dk, rk), (dv, rv)):
+        assert got.dtype == exp.dtype
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(exp, np.float32), **TOL[dtype])
+
+
+# (Sq, Sk, dk, dv, on a TPU) -> the implementation attention(impl="auto")
+# picks, as counted under repro.obs.TRACE_COUNTS
+AUTO_CASES = [
+    (256, 256, 64, 64, True, "flash"),      # full-sequence self-attention
+    (2048, 2048, 64, 64, True, "flash"),    # granite's training shape
+    (4096, 4096, 128, 128, True, "flash"),  # above 2048 too
+    (192, 192, 64, 64, True, "dense"),      # S not a multiple of 128
+    (128, 256, 64, 64, True, "dense"),      # Sq != Sk (a chunk on a cache)
+    (1, 4096, 64, 64, True, "chunked"),     # decode-shaped, long cache
+    (256, 256, 192, 128, True, "dense"),    # MLA: dk != dv
+    (256, 256, 64, 64, False, "dense"),     # not a TPU
+    (4096, 4096, 64, 64, False, "chunked"),
+]
+
+
+@pytest.mark.parametrize("sq,sk,dk,dv,tpu,impl", AUTO_CASES)
+def test_auto_attention_dispatch(monkeypatch, sq, sk, dk, dv, tpu, impl):
+    from repro.models import attention as A
+    from repro.obs import TRACE_COUNTS
+
+    monkeypatch.setattr(A, "_on_tpu", lambda: tpu)
+    B, H, KV = 1, 4, 2
+    sds = jax.ShapeDtypeStruct
+    q = sds((B, sq, H, dk), jnp.bfloat16)
+    k = sds((B, sk, KV, dk), jnp.bfloat16)
+    v = sds((B, sk, KV, dv), jnp.bfloat16)
+    qp = sds((B, sq), jnp.int32)
+    kp = sds((B, sk), jnp.int32)
+    names = ("flash", "dense", "chunked")
+    before = {n: TRACE_COUNTS.counter(f"attention/{n}").value for n in names}
+    out = jax.eval_shape(lambda *a: A.attention(*a, scale=dk ** -0.5),
+                         q, k, v, qp, kp)
+    assert out.shape == (B, sq, H, dv)
+    after = {n: TRACE_COUNTS.counter(f"attention/{n}").value for n in names}
+    assert {n: after[n] - before[n] for n in names} == \
+        {n: float(n == impl) for n in names}
+
+
+def test_auto_attention_on_tpu_trains_through_the_kernel(monkeypatch):
+    """With the TPU probe on, impl="auto" runs the differentiable kernel
+    (interpreted here): output and gradients equal the dense path's, and
+    impl="pallas" reaches the same kernel."""
+    from repro.models import attention as A
+
+    B, S, H, KV, D = 1, 128, 4, 2, 64
+    ks = jax.random.split(jax.random.PRNGKey(8), 3)
+    q = jax.random.normal(ks[0], (B, S, H, D))
+    k = jax.random.normal(ks[1], (B, S, KV, D))
+    v = jax.random.normal(ks[2], (B, S, KV, D))
+    pos = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
+
+    def grads(impl):
+        def f(q, k, v):
+            o = A.attention(q, k, v, pos, pos, scale=D ** -0.5, impl=impl)
+            return jnp.sum(o * o), o
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    want = grads("dense")
+    monkeypatch.setattr(A, "_on_tpu", lambda: True)
+    for impl in ("auto", "pallas"):
+        got = grads(impl)
+        for g, w in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       **TOL[jnp.float32])

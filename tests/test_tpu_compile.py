@@ -10,6 +10,7 @@ compilation cache is off around these compiles; entries written for a
 described chip cannot be read back without one.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,9 +83,33 @@ def _ssd(sds):
                 sds((B, S, SSD_N), f32))
 
 
-@pytest.mark.parametrize("build", [_flash, _decode, _paged, _ssd],
+def _flash_kernel(kernel):
+    """One of the flash kernels at granite-3-2b's training widths."""
+    def build(sds):
+        from repro.kernels import flash_attention as fa
+
+        b, s = 4, 2048
+        fwd, bwd = fa.block_sizes(s, s, D)
+        plan = fa._Plan(scale=D ** -0.5, window=0, cap=0.0, sk_real=s,
+                        fwd=fwd, bwd=bwd, interpret=False)
+        q, kv = sds((b, H, D, s)), sds((b, KV, D, s))  # (B, H, D, S)
+        row = sds((b, H, 1, s), jnp.float32)
+        if kernel == "forward":
+            return (lambda q, k, v: fa._forward(q, k, v, plan)), (q, kv, kv)
+        fn = fa._dkv if kernel == "dkv" else fa._dq
+        return (lambda *a: fn(*a, plan)), (q, kv, kv, q, row, row)
+    return build
+
+
+FLASH_KERNELS = ("forward", "dkv", "dq")
+
+
+@pytest.mark.parametrize(
+    "build", [_flash, _decode, _paged, _ssd]
+    + [_flash_kernel(k) for k in FLASH_KERNELS],
                          ids=["flash_attention", "decode_attention",
-                              "paged_decode_attention", "ssd_scan"])
+                              "paged_decode_attention", "ssd_scan"]
+                         + [f"flash_{k}_granite" for k in FLASH_KERNELS])
 def test_kernel_compiles_for_v5e(one_chip, build):
     def sds(shape, dtype=jnp.bfloat16):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -94,10 +119,10 @@ def test_kernel_compiles_for_v5e(one_chip, build):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_granite_train_step_fits_one_v5e(one_chip):
-    """The step chip_smoke.py trains: published widths, 8 of 40 layers,
-    batch 4 x 2048, float32 AdamW, block remat — as Session.train runs
-    it."""
+def _granite_step_memory(one_chip, attn_impl):
+    """Compile the step chip_smoke.py trains (published widths, 8 of 40
+    layers, batch 4 x 2048, float32 AdamW, block remat) as Session.train
+    runs it; its memory analysis and optimized HLO."""
     from repro.configs.base import get_config
     from repro.launch.steps import build_train_step
     from repro.models import model as M
@@ -107,7 +132,7 @@ def test_granite_train_step_fits_one_v5e(one_chip):
 
     cfg = get_config("granite-3-2b").replace(num_layers=8)
     opt = OptConfig(lr=1e-3, warmup_steps=1, total_steps=4)
-    run = RunConfig(attn_impl="auto", remat="block")
+    run = RunConfig(attn_impl=attn_impl, remat="block")
 
     def on_chip(tree):
         return jax.tree_util.tree_map(
@@ -119,7 +144,32 @@ def test_granite_train_step_fits_one_v5e(one_chip):
     state = on_chip(jax.eval_shape(lambda p: init_state(opt, p), params))
     tok = jax.ShapeDtypeStruct((4, 2048), jnp.int32, sharding=one_chip)
     step = jax.jit(build_train_step(cfg, run, opt), donate_argnums=(0, 1))
-    ma = step.lower(params, state, {"tokens": tok, "labels": tok}
-                    ).compile().memory_analysis()
+    compiled = step.lower(params, state, {"tokens": tok, "labels": tok}
+                          ).compile()
+    return compiled.memory_analysis(), compiled.as_text()
+
+
+def test_granite_train_step_fits_one_v5e(one_chip):
+    """The step chip_smoke.py trains: published widths, 8 of 40 layers,
+    batch 4 x 2048, float32 AdamW, block remat — as Session.train runs
+    it."""
+    ma, _ = _granite_step_memory(one_chip, "auto")
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used < 16 * GIB, f"{used / GIB:.2f} GiB"
+
+
+def test_granite_flash_train_step_fits_one_v5e(one_chip, monkeypatch):
+    """The same step through the Pallas flash kernel, as it runs on a TPU:
+    kernels compiled (not interpreted), no S x S score tensor, and fewer
+    temporaries than the dense step's 5.88e9 B."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    ma, text = _granite_step_memory(one_chip, "pallas")
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used < 16 * GIB, f"{used / GIB:.2f} GiB"
+    # forward, dK/dV and dQ: the block remat keeps the forward's output
+    # and log-sum-exp, so its recompute runs no forward kernel
+    assert text.count("tpu_custom_call") == 3
+    assert not re.search(r"\[4,(8,4|32),2048,2048\]", text)
+    assert ma.temp_size_in_bytes < 5.88e9, ma.temp_size_in_bytes
