@@ -119,28 +119,31 @@ def _flash_geometry(Sq: int, Sk: int, q_block: int, kv_block: int):
 
 
 def flash_contract(*, B: int, H: int, KV: int, Sq: int, Sk: int, D: int,
-                   dtype_bytes: int = 2, q_block: int = 512,
-                   kv_block: int = 512, context: str = "flash_attention",
+                   Dv: Optional[int] = None, dtype_bytes: int = 2,
+                   q_block: int = 512, kv_block: int = 512,
+                   context: str = "flash_attention",
                    ) -> Tuple[Optional[KernelContract], List[Finding]]:
     """Mirror of the forward kernel of ``kernels.flash_attention``: q, k, v
-    and o as (B, heads, D, S) with the sequence in the lanes, tq/tk clamped
-    to the padded sequence, grid (B, H, nq, nk); q as (tq, D) rows, f32
-    accumulator and lane-replicated running max/sum in scratch; the f32
-    log-sum-exp written as (B, H, 1, Sq) rows."""
+    and o as (B, heads, D, S) with the sequence in the lanes (v and o at
+    the value head ``Dv``, ``D`` unless given: MLA's is narrower), tq/tk
+    clamped to the padded sequence, grid (B, H, nq, nk); q as (tq, D) rows,
+    a (tq, Dv) f32 accumulator and lane-replicated running max/sum in
+    scratch; the f32 log-sum-exp written as (B, H, 1, Sq) rows."""
     op = "flash_attention"
     bad = _gqa_finding(op, H, KV, context)
     if bad:
         return None, bad
+    Dv = Dv or D
     tq, tk, sq_p, sk_p = _flash_geometry(Sq, Sk, q_block, kv_block)
     grid = (B, H, sq_p // tq, sk_p // tk)
     blocks = (
         Block("q", (1, 1, D, tq), dtype_bytes, "in", (B, H, D, sq_p)),
         Block("k", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
-        Block("v", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
-        Block("out", (1, 1, D, tq), dtype_bytes, "out", (B, H, D, sq_p)),
+        Block("v", (1, 1, Dv, tk), dtype_bytes, "in", (B, KV, Dv, sk_p)),
+        Block("out", (1, 1, Dv, tq), dtype_bytes, "out", (B, H, Dv, sq_p)),
         Block("lse", (1, 1, 1, tq), 4, "out", (B, H, 1, sq_p)),
         Block("q_rows", (tq, D), dtype_bytes, "scratch"),
-        Block("acc", (tq, D), 4, "scratch"),
+        Block("acc", (tq, Dv), 4, "scratch"),
         Block("m_run", (tq, 128), 4, "scratch"),
         Block("l_run", (tq, 128), 4, "scratch"),
     )
@@ -148,20 +151,22 @@ def flash_contract(*, B: int, H: int, KV: int, Sq: int, Sk: int, D: int,
 
 
 def flash_bwd_contracts(*, B: int, H: int, KV: int, Sq: int, Sk: int,
-                        D: int, dtype_bytes: int = 2, q_block: int = 512,
+                        D: int, Dv: Optional[int] = None,
+                        dtype_bytes: int = 2, q_block: int = 512,
                         kv_block: int = 512, context: str = "flash_attention",
                         ) -> Tuple[List[KernelContract], List[Finding]]:
     """Mirrors of the two backward kernels: dK/dV on grid (B, KV, nk, G,
-    nq) with k and v as (tk, D) rows and (tk, D) f32 dK and dV
-    accumulators in scratch, and dQ on grid (B, H, nq, nk) with q and dO
-    as (tq, D) rows and a (tq, D) f32 accumulator.  Both read (B, heads,
-    D, S) blocks of q, dO, k, v and the f32 log-sum-exp and
-    ``rowsum(dO * O)`` as (1, tq) rows.  Their contexts are ``context``
-    with ``.dkv`` / ``.dq`` after the op."""
+    nq) with k and v as (tk, D) and (tk, Dv) rows and f32 dK and dV
+    accumulators of those shapes in scratch, and dQ on grid (B, H, nq, nk)
+    with q and dO as (tq, D) and (tq, Dv) rows and a (tq, D) f32
+    accumulator.  Both read (B, heads, D or Dv, S) blocks of q, dO, k, v
+    and the f32 log-sum-exp and ``rowsum(dO * O)`` as (1, tq) rows.  Their
+    contexts are ``context`` with ``.dkv`` / ``.dq`` after the op."""
     op = "flash_attention"
     bad = _gqa_finding(op, H, KV, context)
     if bad:
         return [], bad
+    Dv = Dv or D
     tq, tk, sq_p, sk_p = _flash_geometry(Sq, Sk, q_block, kv_block)
     nq, nk = sq_p // tq, sk_p // tk
     head, sep, rest = context.partition(":")
@@ -171,28 +176,29 @@ def flash_bwd_contracts(*, B: int, H: int, KV: int, Sq: int, Sk: int,
 
     q_side = (
         Block("q", (1, 1, D, tq), dtype_bytes, "in", (B, H, D, sq_p)),
-        Block("do", (1, 1, D, tq), dtype_bytes, "in", (B, H, D, sq_p)),
+        Block("do", (1, 1, Dv, tq), dtype_bytes, "in", (B, H, Dv, sq_p)),
         Block("lse", (1, 1, 1, tq), 4, "in", (B, H, 1, sq_p)),
         Block("delta", (1, 1, 1, tq), 4, "in", (B, H, 1, sq_p)),
     )
     kv_side = (
         Block("k", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
-        Block("v", (1, 1, D, tk), dtype_bytes, "in", (B, KV, D, sk_p)),
+        Block("v", (1, 1, Dv, tk), dtype_bytes, "in", (B, KV, Dv, sk_p)),
     )
     dkv = KernelContract(
         op, sub("dkv"), (B, KV, nk, H // KV, nq), q_side + kv_side + (
             Block("dk", (1, 1, D, tk), dtype_bytes, "out", (B, KV, D, sk_p)),
-            Block("dv", (1, 1, D, tk), dtype_bytes, "out", (B, KV, D, sk_p)),
+            Block("dv", (1, 1, Dv, tk), dtype_bytes, "out",
+                  (B, KV, Dv, sk_p)),
             Block("k_rows", (tk, D), dtype_bytes, "scratch"),
-            Block("v_rows", (tk, D), dtype_bytes, "scratch"),
+            Block("v_rows", (tk, Dv), dtype_bytes, "scratch"),
             Block("dk_acc", (tk, D), 4, "scratch"),
-            Block("dv_acc", (tk, D), 4, "scratch"),
+            Block("dv_acc", (tk, Dv), 4, "scratch"),
         ))
     dq = KernelContract(
         op, sub("dq"), (B, H, nq, nk), q_side + kv_side + (
             Block("dq", (1, 1, D, tq), dtype_bytes, "out", (B, H, D, sq_p)),
             Block("q_rows", (tq, D), dtype_bytes, "scratch"),
-            Block("do_rows", (tq, D), dtype_bytes, "scratch"),
+            Block("do_rows", (tq, Dv), dtype_bytes, "scratch"),
             Block("dq_acc", (tq, D), 4, "scratch"),
         ))
     return [dkv, dq], []
@@ -375,8 +381,13 @@ def registry_contracts(
     for arch in ARCH_IDS:
         cfg = get_config(arch)
         if cfg.has_attention:
-            H, KV, D = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+            H, KV, D, Dv = (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
+                            cfg.head_dim)
             if cfg.is_mla:
+                # full-sequence MLA: per-head q/k of qk_nope + qk_rope and
+                # a narrower value head
+                D = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+                Dv = cfg.v_head_dim
                 # absorbed MLA decode: one shared latent "KV head" of
                 # width kv_lora_rank + qk_rope_head_dim (576 for
                 # deepseek-v2) — the wide-lane case KC102 must admit
@@ -390,10 +401,10 @@ def registry_contracts(
                     ctx = f"flash_attention:{arch}:{shape}:{DTYPE_NAMES[db]}"
                     add("flash_attention",
                         flash_contract(B=batch, H=H, KV=KV, Sq=s, Sk=s,
-                                       D=D, dtype_bytes=db, q_block=tq,
-                                       kv_block=tk, context=ctx))
+                                       D=D, Dv=Dv, dtype_bytes=db,
+                                       q_block=tq, kv_block=tk, context=ctx))
                     bwd, fs = flash_bwd_contracts(
-                        B=batch, H=H, KV=KV, Sq=s, Sk=s, D=D,
+                        B=batch, H=H, KV=KV, Sq=s, Sk=s, D=D, Dv=Dv,
                         dtype_bytes=db, q_block=bq, kv_block=bk,
                         context=ctx)
                     for c in bwd:

@@ -57,12 +57,19 @@ class ModelConfig:
     # attention
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # YaRN rope scaling (deepseek-v2): off while yarn_factor is 0
+    yarn_factor: float = 0.0
+    yarn_original_max_position: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
     sliding_window: int = 0  # window size for "swa" mixer slots
     attn_softcap: float = 0.0
     logit_softcap: float = 0.0
     attn_window_override: int = 0  # >0: force SWA at serve time (long_500k variant)
 
-    # MLA (deepseek-v2 / minicpm3)
+    # MLA (deepseek-v2 / minicpm3); q_lora_rank 0 projects q directly
     kv_lora_rank: int = 0
     q_lora_rank: int = 0
     qk_nope_head_dim: int = 0
@@ -74,6 +81,14 @@ class ModelConfig:
     num_shared_experts: int = 0
     top_k: int = 0
     moe_d_ff: int = 0
+    norm_topk_prob: bool = True  # renormalise the top-k router weights
+    routed_scaling_factor: float = 1.0
+    seq_aux: bool = False  # load-balance loss per sequence, not per batch
+    aux_loss_alpha: float = 0.01
+    # the experts this device holds, [expert_offset, expert_offset +
+    # experts_held) of num_experts; 0 holds them all
+    experts_held: int = 0
+    expert_offset: int = 0
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -115,6 +130,10 @@ class ModelConfig:
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def is_mla(self) -> bool:
@@ -188,9 +207,11 @@ class ModelConfig:
         kw["num_layers"] = len(pattern)  # one cycle
         if self.num_experts:
             kw.update(num_experts=4, top_k=min(self.top_k, 2), moe_d_ff=256,
-                      num_shared_experts=min(self.num_shared_experts, 1))
+                      num_shared_experts=min(self.num_shared_experts, 1),
+                      experts_held=0, expert_offset=0)
         if self.kv_lora_rank:
-            kw.update(kv_lora_rank=64, q_lora_rank=128, qk_nope_head_dim=32,
+            kw.update(kv_lora_rank=64, q_lora_rank=128 if self.q_lora_rank else 0,
+                      qk_nope_head_dim=32,
                       qk_rope_head_dim=16, v_head_dim=32)
         if self.ssm_state:
             kw.update(ssm_state=32, ssm_head_dim=32, ssm_chunk=32)
@@ -236,6 +257,7 @@ ARCH_IDS = [
     "arctic-480b",
     "llava-next-34b",
     "deepseek-v2-236b",
+    "deepseek-v2-lite",
     "gemma2-27b",
     "granite-3-2b",
     "minicpm3-4b",
@@ -249,6 +271,7 @@ _MODULES = {
     "arctic-480b": "arctic_480b",
     "llava-next-34b": "llava_next_34b",
     "deepseek-v2-236b": "deepseek_v2_236b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "gemma2-27b": "gemma2_27b",
     "granite-3-2b": "granite_3_2b",
     "minicpm3-4b": "minicpm3_4b",

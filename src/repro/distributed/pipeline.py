@@ -235,7 +235,7 @@ class PipelineTrainer:
 
         The carry between stages is ``(h, aux)`` — activations plus the
         running MoE aux-loss sum; every stage's aux cotangent is the
-        constant ``0.01`` (the ``aux_weight`` in
+        constant ``cfg.aux_loss_alpha`` (its weight in
         :func:`repro.models.model.loss_fn`), so backward never threads it.
         """
         cfg, run, p = self.cfg, self.run, self.pipe
@@ -258,14 +258,15 @@ class PipelineTrainer:
             """Stage 0 of p > 1: tokens -> (h, aux)."""
             cp = M.cast_params(sp, cfg)
             h, pos = embed_prelude(cp, batch)
-            h, _, aux = M._scan_cycles(cp, h, pos, cfg, run, False)
-            return h, jnp.asarray(aux, jnp.float32)
+            h, _, stats = M._scan_cycles(cp, h, pos, cfg, run, False)
+            return h, stats["aux"]
 
         def mid(sp, h, aux_in):
             """Interior stage: (h, aux) -> (h, aux)."""
             cp = M.cast_params(sp, cfg)
-            h, _, aux = M._scan_cycles(cp, h, _positions(h), cfg, run, False)
-            return h, aux_in + jnp.asarray(aux, jnp.float32)
+            h, _, stats = M._scan_cycles(cp, h, _positions(h), cfg, run,
+                                         False)
+            return h, aux_in + stats["aux"]
 
         def head_loss(cp, h, batch, aux):
             h = rms_norm(h, cp["final_norm"], cfg.norm_eps)
@@ -275,21 +276,21 @@ class PipelineTrainer:
             labels = batch["labels"]
             mask = (labels >= 0).astype(jnp.float32)
             ce = cross_entropy(logits, jnp.maximum(labels, 0), mask)
-            return ce + 0.01 * aux
+            return ce + cfg.aux_loss_alpha * aux
 
         def last(sp, batch, h, aux_in):
             """Final stage of p > 1: (h, aux) + labels -> loss."""
             cp = M.cast_params(sp, cfg)
-            h, _, aux = M._scan_cycles(cp, h, _positions(h), cfg, run, False)
-            return head_loss(cp, h, batch,
-                             aux_in + jnp.asarray(aux, jnp.float32))
+            h, _, stats = M._scan_cycles(cp, h, _positions(h), cfg, run,
+                                         False)
+            return head_loss(cp, h, batch, aux_in + stats["aux"])
 
         def solo(sp, batch):
             """p == 1: the whole model, loss_fn's exact op sequence."""
             cp = M.cast_params(sp, cfg)
             h, pos = embed_prelude(cp, batch)
-            h, _, aux = M._scan_cycles(cp, h, pos, cfg, run, False)
-            return head_loss(cp, h, batch, jnp.asarray(aux, jnp.float32))
+            h, _, stats = M._scan_cycles(cp, h, pos, cfg, run, False)
+            return head_loss(cp, h, batch, stats["aux"])
 
         return first, mid, last, solo
 
@@ -297,7 +298,8 @@ class PipelineTrainer:
         p, dp = self.pipe, self.dp
         strat, comp, m = self.strategy, self.compressor, self.n_microbatch
         first, mid, last, solo = self._inner_fns()
-        cot_aux = jnp.asarray(0.01, jnp.float32)  # d loss / d aux_s
+        # d loss / d aux_s
+        cot_aux = jnp.asarray(self.cfg.aux_loss_alpha, jnp.float32)
 
         # fwd: op call per (stage, microbatch); bwd: jax.vjp recompute.
         # Stacked (leading per-device axis) outputs mirror the baseline's
@@ -376,7 +378,7 @@ class PipelineTrainer:
 
                 if self.cfg.tie_embeddings:
                     def bwd_last(sp, b, h):
-                        # aux_in enters the loss additively (x 0.01): it
+                        # aux_in enters the loss additively (x alpha): it
                         # never touches this stage's cotangents, so
                         # backward runs with aux_in = 0, bitwise identical
                         gp, gh = jax.grad(

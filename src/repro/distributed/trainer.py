@@ -463,6 +463,17 @@ class DataParallelTrainer:
             self._ensure_bucket_plan(params)
         return params, state
 
+    def grads(self, params, batch):
+        """(per-device losses, the synced gradient): the compute and sync
+        phases of a step without the update (and without error feedback)."""
+        losses, gstack = self._grad_fn(params, batch)
+        return losses, self._sync_fn(gstack, None)[0]
+
+    def batch_sharding(self) -> NamedSharding:
+        """Where :meth:`step_fn` takes its batch: rows split over the data
+        axes."""
+        return NamedSharding(self.mesh, self._data_spec)
+
     def step_fn(self):
         """A loop-compatible step callable: (params, opt_state, batch) ->
         (params, opt_state, metrics). Phase wall-times are attached to
@@ -549,9 +560,8 @@ class DataParallelTrainer:
                 for a in jax.tree_util.tree_leaves(params))
         if self.sync_overlap:
             self._ensure_bucket_plan(params)
-        batch_sharding = {
-            k: NamedSharding(self.mesh, self._data_spec)
-            for k in ("tokens", "labels", "image_embeds")}
+        batch_sharding = {k: self.batch_sharding()
+                          for k in ("tokens", "labels", "image_embeds")}
         res = loop_lib.train(
             self.cfg, self.run, self.opt, batch=batch, seq=seq, steps=steps,
             seed=seed, log_every=log_every, params=params,

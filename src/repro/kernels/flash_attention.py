@@ -23,11 +23,12 @@ fully above the causal diagonal or outside the window are skipped with
 so a skipped step issues no copy.  Only blocks that cross the diagonal,
 the window's edge or the padded tail build a mask.
 
-Layout: :func:`flash_attention` takes q (B, H, Sq, D), k/v (B, KV, Sk, D)
-(ops.py transposes the model's (B, S, H, D) to it) and hands the kernels
-(B, heads, D, S): the sequence in the 128-wide lanes, so a head dim of 64
-fills them and the projections around the kernel read and write the same
-layout as dense attention's.  Each kernel turns its q, dO, k or v block
+Layout: :func:`flash_attention` takes q (B, H, Sq, D), k (B, KV, Sk, D)
+and v (B, KV, Sk, Dv) (ops.py transposes the model's (B, S, H, D) to it;
+Dv may differ from D, as in MLA) and hands the kernels (B, heads, D, S):
+the sequence in the 128-wide lanes, so a head dim of 64 fills them and
+the projections around the kernel read and write the same layout as
+dense attention's.  Each kernel turns its q, dO, k or v block
 into (t, D) rows once per block it keeps, so every matmul is a plain or
 a right-transposed product.
 """
@@ -236,30 +237,33 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, q_sc, acc_ref, m_ref,
 
 def _forward(q, k, v, p: _Plan):
     B, H, D, Sq = q.shape
-    KV, Sk = k.shape[1], k.shape[3]
+    KV, Sk, Dv = k.shape[1], k.shape[3], v.shape[2]
     G = H // KV
     tq, tk = p.fwd
     nq, nk = Sq // tq, Sk // tk
+
+    def q_map(b, h, qi, ki):
+        return b, h, 0, qi
 
     def kv_map(b, h, qi, ki):
         lo, hi = _k_range(qi, tq, tk, nk, p.window)
         return b, h // G, 0, jnp.clip(ki, lo, hi)
 
-    q_spec = pl.BlockSpec((1, 1, D, tq), lambda b, h, qi, ki: (b, h, 0, qi))
-    kv_spec = pl.BlockSpec((1, 1, D, tk), kv_map)
     return pl.pallas_call(
         functools.partial(_fwd_kernel, p=p, nk=nk),
         grid=(B, H, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        in_specs=[pl.BlockSpec((1, 1, D, tq), q_map),
+                  pl.BlockSpec((1, 1, D, tk), kv_map),
+                  pl.BlockSpec((1, 1, Dv, tk), kv_map)],
         out_specs=[
-            q_spec,
-            pl.BlockSpec((1, 1, 1, tq), lambda b, h, qi, ki: (b, h, 0, qi)),
+            pl.BlockSpec((1, 1, Dv, tq), q_map),
+            pl.BlockSpec((1, 1, 1, tq), q_map),
         ],
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, H, Dv, Sq), q.dtype),
                    jax.ShapeDtypeStruct((B, H, 1, Sq), jnp.float32)],
         scratch_shapes=[
             pltpu.VMEM((tq, D), q.dtype),
-            pltpu.VMEM((tq, D), jnp.float32),
+            pltpu.VMEM((tq, Dv), jnp.float32),
             pltpu.VMEM((tq, 128), jnp.float32),
             pltpu.VMEM((tq, 128), jnp.float32),
         ],
@@ -327,7 +331,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dk_ref, dv_ref,
 
 def _dkv(q, k, v, do, lse, dl, p: _Plan):
     B, H, D, Sq = q.shape
-    KV, Sk = k.shape[1], k.shape[3]
+    KV, Sk, Dv = k.shape[1], k.shape[3], v.shape[2]
     G = H // KV
     tq, tk = p.bwd
     nq, nk = Sq // tq, Sk // tk
@@ -343,19 +347,21 @@ def _dkv(q, k, v, do, lse, dl, p: _Plan):
         return b, kv, 0, ki
 
     q_spec = pl.BlockSpec((1, 1, D, tq), q_map)
-    kv_spec = pl.BlockSpec((1, 1, D, tk), kv_map)
+    do_spec = pl.BlockSpec((1, 1, Dv, tq), q_map)
+    k_spec = pl.BlockSpec((1, 1, D, tk), kv_map)
+    v_spec = pl.BlockSpec((1, 1, Dv, tk), kv_map)
     row_spec = pl.BlockSpec((1, 1, 1, tq), q_map)
     return pl.pallas_call(
         functools.partial(_dkv_kernel, p=p, G=G, nq=nq),
         grid=(B, KV, nk, G, nq),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[kv_spec, kv_spec],
+        in_specs=[q_spec, k_spec, v_spec, do_spec, row_spec, row_spec],
+        out_specs=[k_spec, v_spec],
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[pltpu.VMEM((tk, D), k.dtype),
-                        pltpu.VMEM((tk, D), v.dtype),
+                        pltpu.VMEM((tk, Dv), v.dtype),
                         pltpu.VMEM((tk, D), jnp.float32),
-                        pltpu.VMEM((tk, D), jnp.float32)],
+                        pltpu.VMEM((tk, Dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary", "arbitrary")),
@@ -413,7 +419,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dl_ref, dq_ref, q_sc,
 
 def _dq(q, k, v, do, lse, dl, p: _Plan):
     B, H, D, Sq = q.shape
-    KV, Sk = k.shape[1], k.shape[3]
+    KV, Sk, Dv = k.shape[1], k.shape[3], v.shape[2]
     G = H // KV
     tq, tk = p.bwd
     nq, nk = Sq // tq, Sk // tk
@@ -422,17 +428,22 @@ def _dq(q, k, v, do, lse, dl, p: _Plan):
         lo, hi = _k_range(qi, tq, tk, nk, p.window)
         return b, h // G, 0, jnp.clip(ki, lo, hi)
 
-    q_spec = pl.BlockSpec((1, 1, D, tq), lambda b, h, qi, ki: (b, h, 0, qi))
-    row_spec = pl.BlockSpec((1, 1, 1, tq), lambda b, h, qi, ki: (b, h, 0, qi))
-    kv_spec = pl.BlockSpec((1, 1, D, tk), kv_map)
+    def q_map(b, h, qi, ki):
+        return b, h, 0, qi
+
+    q_spec = pl.BlockSpec((1, 1, D, tq), q_map)
     return pl.pallas_call(
         functools.partial(_dq_kernel, p=p, nk=nk),
         grid=(B, H, nq, nk),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, pl.BlockSpec((1, 1, D, tk), kv_map),
+                  pl.BlockSpec((1, 1, Dv, tk), kv_map),
+                  pl.BlockSpec((1, 1, Dv, tq), q_map),
+                  pl.BlockSpec((1, 1, 1, tq), q_map),
+                  pl.BlockSpec((1, 1, 1, tq), q_map)],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((tq, D), q.dtype),
-                        pltpu.VMEM((tq, D), do.dtype),
+                        pltpu.VMEM((tq, Dv), do.dtype),
                         pltpu.VMEM((tq, D), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -473,7 +484,9 @@ _attend.defvjp(_attend_fwd, _attend_bwd)
 def flash_attention(q, k, v, *, scale: float, window: int = 0,
                     cap: float = 0.0, q_block: int | None = None,
                     kv_block: int | None = None, interpret: bool):
-    """q (B,H,Sq,D), k/v (B,KV,Sk,D) -> (B,H,Sq,D). Causal, differentiable.
+    """q (B,H,Sq,D), k (B,KV,Sk,D), v (B,KV,Sk,Dv) -> (B,H,Sq,Dv). Causal,
+    differentiable; the value head may differ from the query and key head
+    (MLA: D 192, Dv 128).
 
     ``q_block`` / ``kv_block`` set every kernel's blocks (tests use them to
     span several blocks at small sizes); left out, :func:`block_sizes`

@@ -35,6 +35,30 @@ def flash_attention(q, k, v, q_pos=None, k_pos=None, *, scale, window=0,
     return out.transpose(0, 2, 1, 3)
 
 
+# Tiles of the grouped matmul: 512 rows, and a whole K or N dimension up
+# to 1536 wide (DeepSeek-V2-Lite's expert width 1408 = 11 x 128 has no
+# smaller 128-multiple divisor), else 512 of it.
+GMM_ROWS, GMM_FULL, GMM_COLS = 512, 1536, 512
+
+
+def gmm_tiling(m: int, k: int, n: int):
+    """(tm, tk, tn) of megablox's ``gmm`` / ``tgmm`` for an (m, k) x (k, n)
+    problem; ``m`` is a multiple of ``min(m, GMM_ROWS)``."""
+    return (min(m, GMM_ROWS), k if k <= GMM_FULL else GMM_COLS,
+            n if n <= GMM_FULL else GMM_COLS)
+
+
+def grouped_matmul(lhs, rhs, group_sizes):
+    """lhs (M, K) rows sorted by group, rhs (G, K, N), group_sizes (G',)
+    int32 with G' >= G -> (M, N) in lhs's dtype: row block g of lhs times
+    rhs[g] for the first G groups, zeros for the rows of later groups.
+    Differentiable (megablox's ``gmm`` with its ``tgmm`` backward)."""
+    from jax.experimental.pallas.ops.tpu.megablox import ops as mb
+
+    return mb.gmm(lhs, rhs, group_sizes.astype(jnp.int32), lhs.dtype,
+                  gmm_tiling, None, None, False, _interpret())
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "window", "cap"))
 def decode_attention(q, k, v, pos, *, scale, window=0, cap=0.0):
     """q (B,1,H,D), cache k/v (B,S,KV,D), pos (B,) -> (B,1,H,D)."""

@@ -54,15 +54,22 @@ def mla_specs(cfg: ModelConfig, layers: int) -> Dict[str, ParamSpec]:
     qlr, kvlr = cfg.q_lora_rank, cfg.kv_lora_rank
     L = (layers,)
     la = ("layers",)
-    return {
-        "wq_down": ParamSpec(L + (D, qlr), la + ("embed", "lora")),
-        "q_norm": ParamSpec(L + (qlr,), la + ("lora",), init="zeros"),
-        "wq_up": ParamSpec(L + (qlr, H, nope + rdim), la + ("lora", "q_heads", None)),
+    if qlr:
+        q = {
+            "wq_down": ParamSpec(L + (D, qlr), la + ("embed", "lora")),
+            "q_norm": ParamSpec(L + (qlr,), la + ("lora",), init="zeros"),
+            "wq_up": ParamSpec(L + (qlr, H, nope + rdim),
+                               la + ("lora", "q_heads", None)),
+        }
+    else:  # no q-LoRA (deepseek-v2-lite): q projected directly
+        q = {"wq": ParamSpec(L + (D, H, nope + rdim),
+                             la + ("embed", "q_heads", None))}
+    return dict(q, **{
         "wkv_down": ParamSpec(L + (D, kvlr + rdim), la + ("embed", None)),
         "kv_norm": ParamSpec(L + (kvlr,), la + (None,), init="zeros"),
         "wkv_up": ParamSpec(L + (kvlr, H, nope + vdim), la + (None, "q_heads", None)),
         "wo": ParamSpec(L + (H, vdim, D), la + ("q_heads", None, "embed")),
-    }
+    })
 
 
 def attn_specs(cfg: ModelConfig, mixer: str, layers: int) -> Dict[str, ParamSpec]:
@@ -186,10 +193,10 @@ def _on_tpu() -> bool:
 def _auto_impl(q, k, v) -> str:
     """``impl="auto"``: the Pallas flash kernel for full-sequence
     self-attention on a TPU (the train and prefill paths, whose positions
-    are ``arange(S)``), else dense up to 2048 keys and chunked above."""
+    are ``arange(S)``; MLA's value head may be narrower than its query and
+    key head), else dense up to 2048 keys and chunked above."""
     S = q.shape[1]
-    if (_on_tpu() and k.shape[1] == S and q.shape[-1] == v.shape[-1]
-            and S % 128 == 0):
+    if _on_tpu() and k.shape[1] == S and S % 128 == 0:
         return "pallas"
     return "chunked" if k.shape[1] > 2048 else "dense"
 
@@ -379,19 +386,50 @@ def _cache_write(cache, new, pos):
 # ---------------------------------------------------------------------------
 
 
+def mla_rope(cfg: ModelConfig):
+    """(inverse frequencies or None, cos/sin factor) of MLA's rotary part:
+    YaRN's when the configuration scales rope, else the plain ones."""
+    if not cfg.yarn_factor:
+        return None, 1.0
+    from repro.models.common import yarn_freqs, yarn_mscale
+
+    freqs = yarn_freqs(cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn_factor,
+                       cfg.yarn_original_max_position, cfg.yarn_beta_fast,
+                       cfg.yarn_beta_slow)
+    mscale = (yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale)
+              / yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim))
+    return freqs, mscale
+
+
+def mla_scale(cfg: ModelConfig) -> float:
+    """Softmax scale: ``(qk_nope + qk_rope)^-1/2``, times YaRN's mscale
+    squared where the configuration scales rope."""
+    scale = 1.0 / np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    if cfg.yarn_factor and cfg.yarn_mscale_all_dim:
+        from repro.models.common import yarn_mscale
+
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale_all_dim) ** 2
+    return float(scale)
+
+
 def _mla_qkv(p, x, positions, cfg: ModelConfig):
     from repro.models.common import rms_norm
 
-    nope, rdim = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
-    cq = rms_norm(x @ p["wq_down"], p["q_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsl,lhk->bshk", cq, p["wq_up"])
+    nope = cfg.qk_nope_head_dim
+    if "wq" in p:
+        q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
+    else:
+        cq = rms_norm(x @ p["wq_down"], p["q_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsl,lhk->bshk", cq, p["wq_up"])
+    freqs, mscale = mla_rope(cfg)
     q_nope, q_rope = q[..., :nope], q[..., nope:]
-    q_rope = rope(q_rope, positions, cfg.rope_theta)
+    q_rope = rope(q_rope, positions, cfg.rope_theta, freqs, mscale)
 
     ckv_full = x @ p["wkv_down"]  # (B,S,kvlr+rdim)
     ckv, k_rope = ckv_full[..., : cfg.kv_lora_rank], ckv_full[..., cfg.kv_lora_rank:]
     ckv = rms_norm(ckv, p["kv_norm"], cfg.norm_eps)
-    k_rope = rope(k_rope[..., None, :], positions, cfg.rope_theta)[..., 0, :]
+    k_rope = rope(k_rope[..., None, :], positions, cfg.rope_theta, freqs,
+                  mscale)[..., 0, :]
     return q_nope, q_rope, ckv, k_rope
 
 
@@ -409,7 +447,7 @@ def mla_forward(p, x, positions, cfg: ModelConfig, mixer: str, *, impl="auto"):
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     out = attention(
         q, k, v, positions, positions,
-        scale=1.0 / np.sqrt(nope + cfg.qk_rope_head_dim),
+        scale=mla_scale(cfg),
         window=_window_for(cfg, mixer),
         cap=cfg.attn_softcap,
         impl=impl,
@@ -433,7 +471,7 @@ def mla_decode(p, x, pos, cache, cfg: ModelConfig, mixer: str,
     w_uk = p["wkv_up"][..., :nope]  # (kvlr, H, nope)
     w_uv = p["wkv_up"][..., nope:]  # (kvlr, H, vdim)
     q_abs = jnp.einsum("bshn,lhn->bshl", q_nope, w_uk)  # absorbed query
-    scale = 1.0 / np.sqrt(nope + cfg.qk_rope_head_dim)
+    scale = mla_scale(cfg)
     logits = (
         jnp.einsum("bshl,bkl->bhsk", q_abs, ckv)
         + jnp.einsum("bshr,bkr->bhsk", q_rope, krope)

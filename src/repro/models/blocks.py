@@ -23,7 +23,6 @@ class RunConfig:
     remat: str = "block"  # none | block
     seq_parallel: bool = False
     microbatch: int = 0  # >0: gradient-accumulation microbatch size
-    capacity_factor: float = 1.25
     # concrete NamedShardings injected by the launcher (None on single host):
     act_sharding: Any = None  # residual stream (B, S, D)
     kv_block: int = 1024
@@ -101,22 +100,22 @@ def _mixer_forward(p, h, positions, cfg, slot: SlotSpec, run: RunConfig):
 
 
 def _mlp_forward(p, h, cfg, slot: SlotSpec, run: RunConfig):
+    """Returns (out, MoE statistics: ``moe.no_stats()`` for a dense MLP)."""
     with scope("mlp"):
         if slot.mlp == "dense":
-            return moe_lib.dense_mlp(p, h), 0.0
-        moe_fn = moe_lib.moe_mlp
-        kw = dict(capacity_factor=run.capacity_factor)
+            return moe_lib.dense_mlp(p, h), moe_lib.no_stats()
+        moe_fn, kw = moe_lib.moe_mlp, {}
         if run.moe_mesh is not None:
             moe_fn = moe_lib.moe_mlp_sharded
-            kw.update(mesh=run.moe_mesh, axis=run.moe_axis)
+            kw = dict(mesh=run.moe_mesh, axis=run.moe_axis)
         if slot.mlp == "moe":
             return moe_fn(p, h, cfg, **kw)
-        y_moe, aux = moe_fn(p["moe"], h, cfg, **kw)
-        return moe_lib.dense_mlp(p["dense"], h) + y_moe, aux
+        y_moe, stats = moe_fn(p["moe"], h, cfg, **kw)
+        return moe_lib.dense_mlp(p["dense"], h) + y_moe, stats
 
 
 def slot_forward(p, h, positions, cfg: ModelConfig, slot: SlotSpec, run: RunConfig):
-    """Returns (h, cache, aux_loss)."""
+    """Returns (h, cache, MoE statistics: ``{"aux", "moe_held_rows"}``)."""
     with scope("block"):
         resid = h
         u = rms_norm(h, p["mixer_norm"], cfg.norm_eps)
@@ -125,15 +124,15 @@ def slot_forward(p, h, positions, cfg: ModelConfig, slot: SlotSpec, run: RunConf
             u = rms_norm(u, p["mixer_post_norm"], cfg.norm_eps)
         h = constrain(resid + u, run.act_sharding)
 
-        aux = 0.0
+        stats = moe_lib.no_stats()
         if "mlp_norm" in p:
             resid = h
             u = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-            u, aux = _mlp_forward(p["mlp"], u, cfg, slot, run)
+            u, stats = _mlp_forward(p["mlp"], u, cfg, slot, run)
             if cfg.use_post_norm:
                 u = rms_norm(u, p["mlp_post_norm"], cfg.norm_eps)
             h = constrain(resid + u, run.act_sharding)
-        return h, cache, aux
+        return h, cache, stats
 
 
 # ---------------------------------------------------------------------------

@@ -121,14 +121,59 @@ def softcap(x: jax.Array, cap: float) -> jax.Array:
     return cap * jnp.tanh(x / cap)
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding. x: (..., S, H, D_rot); positions: (..., S)."""
+def rope_freqs(d: int, theta: float) -> jax.Array:
+    """Inverse frequencies of a ``d``-wide rotary embedding (``d // 2``)."""
+    half = d // 2
+    return theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * float(np.log(factor)) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_ramp(d: int, theta: float, original_max_pos: int,
+              beta_fast: float, beta_slow: float) -> Tuple[int, int]:
+    """First and last frequency index of YaRN's interpolation ramp: the
+    dimensions that turn ``beta_fast`` and ``beta_slow`` times over the
+    original context, clamped to ``[0, d - 1]``."""
+    def dim(rotations):
+        return (d * np.log(original_max_pos / (rotations * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    lo = int(np.floor(dim(beta_fast)))
+    hi = int(np.ceil(dim(beta_slow)))
+    return max(lo, 0), min(hi, d - 1)
+
+
+def yarn_freqs(d: int, theta: float, factor: float, original_max_pos: int,
+               beta_fast: float, beta_slow: float) -> jax.Array:
+    """YaRN inverse frequencies (DeepSeek-V2's ``DeepseekV2YarnRotary
+    Embedding``): the original frequencies above the ramp (index below its
+    first), frequencies divided by ``factor`` below it, blended linearly
+    along it."""
+    lo, hi = yarn_ramp(d, theta, original_max_pos, beta_fast, beta_slow)
+    extra = rope_freqs(d, theta)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - lo)
+                    / max(hi - lo, 1e-3), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         freqs: Optional[jax.Array] = None, mscale: float = 1.0) -> jax.Array:
+    """Rotary embedding (half-split). x: (..., S, H, D_rot); positions:
+    (..., S).  ``freqs`` replaces the plain ``theta`` frequencies (YaRN);
+    ``mscale`` multiplies cos and sin."""
     d = x.shape[-1]
     half = d // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = rope_freqs(d, theta)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, half)
     cos = jnp.cos(angles)[..., :, None, :]  # (..., S, 1, half)
     sin = jnp.sin(angles)[..., :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

@@ -2,7 +2,7 @@
 scan-over-cycles block stack, LM head, loss, and the three entry points
 
   * ``forward``      — full-sequence logits (+ prefill caches)
-  * ``loss_fn``      — masked CE (+ MoE aux)
+  * ``loss_fn``      — masked CE (+ MoE load-balance loss)
   * ``decode_step``  — single-token cached decoding
 
 The stack is grouped by the config's layer-pattern *cycle*: parameters for
@@ -23,6 +23,7 @@ from repro.models.blocks import (RunConfig, constrain, slot_cache_specs,
                                  slot_decode, slot_extend, slot_forward,
                                  slot_specs)
 from repro.models.common import (ParamSpec, cross_entropy, rms_norm, softcap)
+from repro.models.moe import add_stats, no_stats
 from repro.obs.scopes import scope
 
 
@@ -123,62 +124,75 @@ def lm_logits(params, h, cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
+def _remat(run: RunConfig, fn):
+    """``fn`` rematerialised as a block when ``run.remat`` asks for it.
+    The flash kernel's output and log-sum-exp are kept: the backward pass
+    then recomputes the block without the attention forward."""
+    if run.remat == "none":
+        return fn
+    return jax.checkpoint(
+        fn, prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *FLASH_RESIDUALS))
+
+
 def _scan_cycles(params, h, positions, cfg, run, with_cache: bool):
-    """Scan the main pattern cycles. Returns (h, caches, aux_total)."""
+    """Scan the main pattern cycles. Returns (h, caches, MoE statistics
+    summed over the layers: ``{"aux", "moe_held_rows"}``)."""
     slot_names = [f"slot{i}" for i in range(len(cfg.pattern))]
     stacked = {n: params["slots"][n] for n in slot_names}
 
     def cycle(h, cycle_params):
-        caches, aux = {}, 0.0
+        caches, stats = {}, no_stats()
         for n, slot in zip(slot_names, cfg.pattern):
-            h, cache, a = slot_forward(cycle_params[n], h, positions, cfg, slot, run)
+            h, cache, s = slot_forward(cycle_params[n], h, positions, cfg, slot, run)
             caches[n] = cache
-            aux = aux + a
-        return h, (caches, aux)
+            stats = add_stats(stats, s)
+        return h, (caches, stats)
 
-    body = cycle
-    if run.remat != "none":
-        # keep the flash kernel's output and log-sum-exp: the backward pass
-        # then recomputes the block without the attention forward
-        body = jax.checkpoint(
-            cycle, prevent_cse=False,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                *FLASH_RESIDUALS))
+    body = _remat(run, cycle)
 
     if run.unroll_layers:
         n = main_cycles(cfg)
-        caches_list, aux_total = [], 0.0
+        caches_list, total = [], no_stats()
         for i in range(n):
             cp = jax.tree_util.tree_map(lambda a: a[i], stacked)
-            h, (c, aux) = body(h, cp)
-            aux_total = aux_total + aux
+            h, (c, stats) = body(h, cp)
+            total = add_stats(total, stats)
             if with_cache:
                 caches_list.append(c)
         caches = (
             jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *caches_list)
             if with_cache else None
         )
-        return h, caches, aux_total
+        return h, caches, total
 
     def scan_body(h, cycle_params):
-        h, (caches, aux) = body(h, cycle_params)
-        return h, (caches if with_cache else None, aux)
+        h, (caches, stats) = body(h, cycle_params)
+        return h, (caches if with_cache else None, stats)
 
-    h, (caches, auxs) = jax.lax.scan(scan_body, h, stacked)
-    return h, caches, jnp.sum(auxs) if np.ndim(auxs) else auxs
+    h, (caches, stats) = jax.lax.scan(scan_body, h, stacked)
+    return h, caches, jax.tree_util.tree_map(lambda a: jnp.sum(a, 0), stats)
 
 
 def cast_params(params, cfg: ModelConfig):
-    """Compute-dtype view of the (fp32 master) parameters."""
+    """Compute-dtype view of the (fp32 master) parameters; MoE routers stay
+    float32 (the router scores in float32)."""
     dt = jnp.dtype(cfg.dtype)
-    return jax.tree_util.tree_map(
-        lambda a: a.astype(dt) if a.dtype == jnp.float32 else a, params
-    )
+
+    def cast(path, a):
+        if a.dtype != jnp.float32 or any(
+                getattr(k, "key", None) == "router" for k in path):
+            return a
+        return a.astype(dt)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 def forward(params, batch, cfg: ModelConfig, run: RunConfig,
             with_cache: bool = False):
-    """Full-sequence forward. Returns (logits, caches, aux_loss)."""
+    """Full-sequence forward. Returns (logits, caches, MoE statistics
+    ``{"aux", "moe_held_rows"}`` summed over the layers)."""
     params = cast_params(params, cfg)
     h = embed_tokens(params, batch, cfg)
     h = constrain(h, run.act_sharding)
@@ -193,9 +207,10 @@ def forward(params, batch, cfg: ModelConfig, run: RunConfig,
             h, cache, _ = slot_forward(layer_params, h, positions, cfg, pre_slot, run)
             return h, cache if with_cache else None
 
-        h, pre_caches = jax.lax.scan(pre_cycle, h, params["prelude"])
+        h, pre_caches = jax.lax.scan(_remat(run, pre_cycle), h,
+                                     params["prelude"])
 
-    h, caches, aux = _scan_cycles(params, h, positions, cfg, run, with_cache)
+    h, caches, stats = _scan_cycles(params, h, positions, cfg, run, with_cache)
     with scope("head_loss"):
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
         logits = lm_logits(params, h, cfg)
@@ -206,14 +221,16 @@ def forward(params, batch, cfg: ModelConfig, run: RunConfig,
     all_caches = {"slots": caches}
     if cfg.first_k_dense:
         all_caches["prelude"] = pre_caches
-    return logits, (all_caches if with_cache else None), aux
+    return logits, (all_caches if with_cache else None), stats
 
 
-def loss_fn(params, batch, cfg: ModelConfig, run: RunConfig,
-            aux_weight: float = 0.01):
-    """Masked next-token CE. ``labels`` < 0 are ignored. For VLM inputs the
-    image-prefix positions carry no labels (mask handled via label padding)."""
-    logits, _, aux = forward(params, batch, cfg, run)
+def loss_fn(params, batch, cfg: ModelConfig, run: RunConfig):
+    """Masked next-token CE plus ``aux_loss_alpha`` times the MoE
+    load-balance loss. ``labels`` < 0 are ignored. For VLM inputs the
+    image-prefix positions carry no labels (mask handled via label padding).
+    The metrics of an MoE configuration carry ``moe_held_rows``, the
+    (token, expert) assignments its held experts computed."""
+    logits, _, stats = forward(params, batch, cfg, run)
     with scope("head_loss"):
         labels = batch["labels"]
         if "image_embeds" in batch:
@@ -223,7 +240,10 @@ def loss_fn(params, batch, cfg: ModelConfig, run: RunConfig,
             labels = jnp.concatenate([pad, labels], axis=1)
         mask = (labels >= 0).astype(jnp.float32)
         ce = cross_entropy(logits, jnp.maximum(labels, 0), mask)
-    return ce + aux_weight * aux, {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": stats["aux"]}
+    if cfg.has_moe:
+        metrics["moe_held_rows"] = stats["moe_held_rows"]
+    return ce + cfg.aux_loss_alpha * stats["aux"], metrics
 
 
 # ---------------------------------------------------------------------------

@@ -1,15 +1,22 @@
-"""Mixture-of-Experts MLP with sort-based capacity dispatch.
+"""Mixture-of-Experts MLP: a dropless expert layer told which experts it
+holds, and the plain dense (SwiGLU) MLP.
 
-Dispatch is gather/scatter (memory ops), NOT one-hot einsum — a one-hot
-dispatch matmul would inject O(T·E·C·D) fake FLOPs into the HLO and poison
-the roofline compute term. Expert compute is a grouped einsum
-``ecd,edf->ecf`` whose FLOP count equals the true active-expert FLOPs at
-capacity factor 1.0.
+The router scores every token against all ``num_experts`` experts in
+float32 and picks the top-k; the layer holds the experts
+``[expert_offset, expert_offset + held_experts)`` and computes only the
+part of the result they give: the (token, expert) assignments to a held
+expert are sorted by expert, gathered into rows, run through grouped
+matmuls over the held experts' weights, weighted and scatter-added back.
+No assignment is dropped: the row buffer is sized for the most a held
+expert set can receive (``T * min(k, held)``), and the grouped matmul
+computes only the rows the group sizes cover.  Expert parallelism is the
+sum of these partial results over the devices that hold the experts
+(:func:`moe_mlp_sharded`); the shared experts are computed in full.
 
-Experts are sharded on the mesh "model" axis (expert parallelism); the
-scatter/gather into the (E, C, D) buffer is GSPMD's all-to-all analogue.
-Also provides the plain dense (SwiGLU) MLP and arctic's parallel
-dense+MoE residual form.
+Dispatch is gather/scatter (memory ops), not a one-hot einsum, and the
+grouped matmul's FLOPs are those of the rows it covers, so the compute
+term of a roofline counts true active-expert FLOPs.  Also provides
+arctic's parallel dense + MoE residual form (``blocks``).
 """
 from __future__ import annotations
 
@@ -20,6 +27,11 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.common import ParamSpec, swish
+from repro.obs.metrics import TRACE_COUNTS
+from repro.obs.scopes import scope
+
+# rows of the grouped matmul's buffer are padded to a multiple of this
+ROW_TILE = 512
 
 
 # ---------------------------------------------------------------------------
@@ -46,10 +58,12 @@ def dense_mlp(p, x):
 
 
 def moe_specs(cfg: ModelConfig, layers: int) -> Dict[str, ParamSpec]:
-    D, F, E = cfg.d_model, cfg.moe_d_ff, cfg.num_experts
+    """The router over all ``num_experts``; the held experts' weights."""
+    D, F, E = cfg.d_model, cfg.moe_d_ff, cfg.held_experts
     L, la = (layers,), ("layers",)
     s = {
-        "router": ParamSpec(L + (D, E), la + ("embed", None), scale=0.1),
+        "router": ParamSpec(L + (D, cfg.num_experts), la + ("embed", None),
+                            scale=0.1),
         "w_gate": ParamSpec(L + (E, D, F), la + ("experts", "embed", None)),
         "w_up": ParamSpec(L + (E, D, F), la + ("experts", "embed", None)),
         "w_down": ParamSpec(L + (E, F, D), la + ("experts", None, "embed")),
@@ -59,119 +73,141 @@ def moe_specs(cfg: ModelConfig, layers: int) -> Dict[str, ParamSpec]:
     return s
 
 
-def _router_topk(logits: jax.Array, top_k: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """logits (T, E) -> (weights (T,k), experts (T,k) int32, aux_loss scalar)."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = jax.lax.top_k(probs, top_k)
-    w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
-    # Switch-style load-balance aux loss: E * sum_e f_e * p_e
-    E = logits.shape[-1]
-    me = jnp.mean(probs, axis=0)  # mean router prob per expert
-    onehot = jax.nn.one_hot(idx[:, 0], E)  # fraction routed (top-1 proxy)
-    fe = jnp.mean(onehot, axis=0)
-    aux = E * jnp.sum(fe * me)
+def no_stats():
+    """What an MLP without experts adds to the MoE statistics."""
+    return {"aux": jnp.float32(0.0), "moe_held_rows": jnp.int32(0)}
+
+
+def add_stats(a, b):
+    return jax.tree_util.tree_map(jnp.add, a, b)
+
+
+def load_balance_loss(probs, idx, num_experts: int, groups: int):
+    """``sum_e f_e P_e`` averaged over ``groups`` equal runs of tokens (the
+    sequences, for DeepSeek's sequence-wise loss, or the whole batch):
+    ``f_e`` is ``E / k`` times the share of the run's assignments that go
+    to expert e, ``P_e`` the run's mean router probability of e."""
+    T, K = idx.shape
+    E = num_experts
+    counts = jnp.sum(jax.nn.one_hot(idx, E, dtype=jnp.float32), axis=1)
+    counts = counts.reshape(groups, T // groups, E).sum(axis=1)
+    f = counts * (E / (K * (T // groups)))
+    P = jnp.mean(probs.reshape(groups, T // groups, E), axis=1)
+    return jnp.mean(jnp.sum(f * P, axis=-1))
+
+
+def route(xf, router_w, cfg: ModelConfig, groups: int):
+    """Tokens xf (T, D) -> (weights (T, k) f32, experts (T, k) int32,
+    load-balance loss).  Scores over all experts in float32; weights are
+    the top-k probabilities, renormalised if the configuration says so,
+    times ``routed_scaling_factor``."""
+    logits = jnp.dot(xf.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    w, idx = jax.lax.top_k(probs, cfg.top_k)
+    if cfg.norm_topk_prob:
+        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), 1e-9)
+    w = w * cfg.routed_scaling_factor
+    aux = load_balance_loss(probs, idx, cfg.num_experts, groups)
     return w, idx, aux
 
 
-def moe_mlp(p, x, cfg: ModelConfig, *, capacity_factor: float = 1.25):
-    """x (B, S, D) -> (B, S, D); sort-based dispatch with per-expert capacity."""
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _auto_impl() -> str:
+    """The Pallas grouped matmul (megablox ``gmm``) on a TPU, XLA's
+    ``ragged_dot`` elsewhere."""
+    return "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
+
+
+def _grouped(impl: str, rows, w, sizes):
+    """rows (M, K) sorted by group, w (G, K, N), sizes (G + 1,) with the
+    rows past the first G groups last -> (M, N); those rows give zeros."""
+    if impl == "gmm":
+        from repro.kernels import ops as kops
+
+        return kops.grouped_matmul(rows, w, sizes)
+    return jax.lax.ragged_dot(rows, w, sizes[:-1],
+                              preferred_element_type=rows.dtype)
+
+
+def routed_experts(xf, w, idx, wg, wu, wd, *, e_lo, impl: str = "auto"
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """The held experts' part of the routed result.
+
+    xf (T, D) tokens; w, idx (T, k) the router's weights and experts; wg,
+    wu (G, D, F) and wd (G, F, D) the G held experts, global experts
+    ``[e_lo, e_lo + G)`` (``e_lo`` may be traced).  Returns (out (T, D)
+    float32, the number of assignments the held experts computed)."""
+    T, D = xf.shape
+    K = idx.shape[1]
+    G = wg.shape[0]
+    if impl == "auto":
+        impl = _auto_impl()
+    TRACE_COUNTS.inc("moe/" + impl)
+    with scope("moe_dispatch"):
+        need = T * min(K, G)  # the most assignments G experts can receive
+        M = _round_up(need, ROW_TILE if need >= ROW_TILE else 8)
+        local = idx.reshape(-1) - e_lo
+        held = (local >= 0) & (local < G)
+        key = jnp.where(held, local, G).astype(jnp.int32)
+        flat_w = w.reshape(-1)
+        if M > T * K:  # pad with assignments to no held expert
+            key = jnp.pad(key, (0, M - T * K), constant_values=G)
+            flat_w = jnp.pad(flat_w, (0, M - T * K))
+        order = jnp.argsort(key, stable=True)[:M]
+        counts = jnp.zeros((G + 1,), jnp.int32).at[key].add(1)
+        n_held = jnp.sum(counts[:G])
+        sizes = counts.at[G].set(M - n_held)
+        active = key[order] < G
+        tok = jnp.minimum(order // K, T - 1)
+        rows = jnp.where(active[:, None], xf[tok], 0).astype(xf.dtype)
+    with scope("moe_experts"):
+        h = (swish(_grouped(impl, rows, wg, sizes))
+             * _grouped(impl, rows, wu, sizes))
+        y = _grouped(impl, h.astype(xf.dtype), wd, sizes)
+    with scope("moe_dispatch"):
+        wts = jnp.where(active, flat_w[order], 0.0)
+        out = jnp.zeros((T, D), jnp.float32).at[tok].add(
+            jnp.where(active[:, None], y.astype(jnp.float32) * wts[:, None],
+                      0.0))
+    return out, n_held
+
+
+def moe_mlp(p, x, cfg: ModelConfig, *, impl: str = "auto"):
+    """x (B, S, D) -> (out (B, S, D), {"aux", "moe_held_rows"}): routed
+    over all experts, computed by the held ones, dropless, plus the shared
+    experts."""
     B, S, D = x.shape
-    E, K = cfg.num_experts, cfg.top_k
-    T = B * S
-    xf = x.reshape(T, D)
-
-    w, idx, aux = _router_topk(xf @ p["router"], K)  # (T,K)
-
-    C = int(capacity_factor * T * K / E) + 1
-    C = max(C, 4)
-
-    # flatten (token, k) assignments and sort by expert
-    flat_e = idx.reshape(-1)  # (T*K,)
-    flat_t = jnp.repeat(jnp.arange(T), K)
-    flat_w = w.reshape(-1)
-    order = jnp.argsort(flat_e)  # stable
-    se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-    # position of each assignment within its expert group
-    expert_start = jnp.searchsorted(se, jnp.arange(E))  # (E,)
-    pos = jnp.arange(T * K) - expert_start[se]
-    keep = pos < C
-    slot = jnp.where(keep, se * C + pos, E * C)  # overflow -> dropped row
-
-    # dispatch: buffer (E*C+1, D); last row is the drop bin
-    buf = jnp.zeros((E * C + 1, D), x.dtype).at[slot].set(xf[st])
-    h = buf[: E * C].reshape(E, C, D)
-    y = (
-        jnp.einsum("ecf,efd->ecd",
-                   swish(jnp.einsum("ecd,edf->ecf", h, p["w_gate"]))
-                   * jnp.einsum("ecd,edf->ecf", h, p["w_up"]),
-                   p["w_down"])
-    )
-    y = jnp.concatenate([y.reshape(E * C, D), jnp.zeros((1, D), y.dtype)], axis=0)
-
-    # combine
-    out = jnp.zeros((T, D), jnp.float32).at[st].add(
-        (y[slot] * jnp.where(keep, sw, 0.0)[:, None]).astype(jnp.float32)
-    )
+    xf = x.reshape(B * S, D)
+    with scope("moe_dispatch"):
+        w, idx, aux = route(xf, p["router"], cfg, B if cfg.seq_aux else 1)
+    out, held = routed_experts(xf, w, idx, p["w_gate"], p["w_up"],
+                               p["w_down"], e_lo=cfg.expert_offset, impl=impl)
     out = out.astype(x.dtype).reshape(B, S, D)
     if cfg.num_shared_experts:
         out = out + dense_mlp(p["shared"], x)
-    return out, aux
-
-
-def _local_expert_pass(xf, router_w, wg, wu, wd, cfg: ModelConfig,
-                       capacity_factor: float, e_lo, e_loc: int):
-    """Tokens xf (T, D) through the LOCAL experts [e_lo, e_lo + e_loc) only
-    (e_lo may be a traced axis_index; e_loc is static). Returns
-    (partial_out (T, D) f32, aux); the caller reduces across expert shards."""
-    T, D = xf.shape
-    E, K = cfg.num_experts, cfg.top_k
-    E_loc = e_loc
-    w, idx, aux = _router_topk(xf @ router_w, K)
-
-    C = int(capacity_factor * T * K / E) + 1
-    C = max(C, 4)
-
-    flat_e = idx.reshape(-1)
-    flat_t = jnp.repeat(jnp.arange(T), K)
-    flat_w = w.reshape(-1)
-    order = jnp.argsort(flat_e)
-    se, st, sw = flat_e[order], flat_t[order], flat_w[order]
-    expert_start = jnp.searchsorted(se, jnp.arange(E))
-    pos = jnp.arange(T * K) - expert_start[se]
-    local = (se >= e_lo) & (se < e_lo + E_loc) & (pos < C)
-    slot = jnp.where(local, (se - e_lo) * C + pos, E_loc * C)
-
-    buf = jnp.zeros((E_loc * C + 1, D), xf.dtype).at[slot].set(xf[st])
-    h = buf[: E_loc * C].reshape(E_loc, C, D)
-    y = jnp.einsum(
-        "ecf,efd->ecd",
-        swish(jnp.einsum("ecd,edf->ecf", h, wg))
-        * jnp.einsum("ecd,edf->ecf", h, wu),
-        wd)
-    y = jnp.concatenate([y.reshape(E_loc * C, D),
-                         jnp.zeros((1, D), y.dtype)], axis=0)
-    out = jnp.zeros((T, D), jnp.float32).at[st].add(
-        (y[slot] * jnp.where(local, sw, 0.0)[:, None]).astype(jnp.float32))
-    return out, aux
+    return out, {"aux": aux, "moe_held_rows": held}
 
 
 def moe_mlp_sharded(p, x, cfg: ModelConfig, *, mesh, axis: str = "model",
-                    capacity_factor: float = 1.25):
+                    impl: str = "auto"):
     """Expert-parallel MoE via shard_map (§Perf optimization).
 
-    The baseline ``moe_mlp`` scatters into an expert-sharded buffer, which
-    GSPMD lowers to replicated scatters + giant all-reduces. Here each
-    expert shard all-gathers the (sequence-sharded) tokens once, runs ONLY
-    its local experts with local scatters, and the partial outputs are
-    combined with one reduce-scatter back to the sequence-sharded layout:
-    exactly 2 collectives per MoE layer instead of GSPMD's emergent storm.
-    """
+    Each expert shard all-gathers the (sequence-sharded) tokens once, runs
+    :func:`routed_experts` over its local experts, and the partial outputs
+    are combined with one reduce-scatter back to the sequence-sharded
+    layout: exactly 2 collectives per MoE layer instead of the replicated
+    scatters and all-reduces GSPMD emits for a sharded expert buffer."""
     from jax.sharding import PartitionSpec as P
 
     B, S, D = x.shape
     tp = mesh.shape[axis]
     E = cfg.num_experts
     assert E % tp == 0, (E, tp)
+    assert cfg.held_experts == E, "shard the whole expert set, not a share"
     E_loc = E // tp
     dp = tuple(a for a in mesh.axis_names if a != axis)
 
@@ -180,43 +216,49 @@ def moe_mlp_sharded(p, x, cfg: ModelConfig, *, mesh, axis: str = "model",
         x_full = jax.lax.all_gather(xl, axis, axis=1, tiled=True)  # (B_loc,S,D)
         Bl, Sl, _ = x_full.shape
         xf = x_full.reshape(Bl * Sl, D)
-        eidx = jax.lax.axis_index(axis)
-        out, aux = _local_expert_pass(
-            xf, router_w, wg, wu, wd, cfg, capacity_factor,
-            e_lo=eidx * E_loc, e_loc=E_loc)
+        with scope("moe_dispatch"):
+            w, idx, aux = route(xf, router_w, cfg, Bl if cfg.seq_aux else 1)
+        out, held = routed_experts(
+            xf, w, idx, wg, wu, wd,
+            e_lo=jax.lax.axis_index(axis) * E_loc, impl=impl)
         out = out.reshape(Bl, Sl, D).astype(x.dtype)
         # sum partials across expert shards, landing seq-sharded again
         out = jax.lax.psum_scatter(out, axis, scatter_dimension=1, tiled=True)
         aux = jax.lax.pmean(aux, axis)
+        held = jax.lax.psum(held, axis)
         for a in dp:
             aux = jax.lax.pmean(aux, a)
-        return out, aux
+            held = jax.lax.psum(held, a)
+        return out, aux, held
 
-    out, aux = jax.shard_map(
+    out, aux, held = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp, axis, None), P(), P(axis, None, None),
                   P(axis, None, None), P(axis, None, None)),
-        out_specs=(P(dp, axis, None), P()), check_vma=False,
+        out_specs=(P(dp, axis, None), P(), P()), check_vma=False,
     )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if cfg.num_shared_experts:
         out = out + dense_mlp(p["shared"], x)
-    return out, aux
+    return out, {"aux": aux, "moe_held_rows": held}
 
 
 def moe_mlp_ref(p, x, cfg: ModelConfig):
-    """Naive per-token loop-free reference (computes ALL experts; test-only)."""
+    """Every held expert on every token, selected by the router's top-k
+    (test-only): the held experts' routed part plus the shared experts."""
     B, S, D = x.shape
     xf = x.reshape(-1, D)
-    w, idx, _ = _router_topk(xf @ p["router"], cfg.top_k)
+    w, idx, _ = route(xf, p["router"], cfg, 1)
+    G = p["w_gate"].shape[0]
+    local = idx - cfg.expert_offset
+    # (T, G): the router weight of each held expert, 0 where not picked
+    sel = jnp.sum(jax.nn.one_hot(local, G, dtype=jnp.float32) * w[..., None],
+                  axis=1)
     all_y = jnp.einsum(
-        "ecf,efd->ecd",
-        swish(jnp.einsum("td,edf->etf", xf, p["w_gate"]).transpose(0, 1, 2)) *
-        jnp.einsum("td,edf->etf", xf, p["w_up"]),
-        p["w_down"],
-    )  # careful: dims (E,T,D)
-    # gather chosen experts per token
-    picked = all_y[idx, jnp.arange(xf.shape[0])[:, None]]  # (T,K,D)
-    out = jnp.sum(picked * w[..., None], axis=1).astype(x.dtype).reshape(B, S, D)
+        "etf,efd->etd",
+        swish(jnp.einsum("td,edf->etf", xf, p["w_gate"]))
+        * jnp.einsum("td,edf->etf", xf, p["w_up"]),
+        p["w_down"])
+    out = jnp.einsum("te,etd->td", sel, all_y).astype(x.dtype).reshape(B, S, D)
     if cfg.num_shared_experts:
         out = out + dense_mlp(p["shared"], x)
     return out

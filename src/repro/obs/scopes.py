@@ -23,6 +23,10 @@ COMPONENTS = (
     "mamba",           # a Mamba-2 mixer: projections, conv, gate, output
     "ssd_scan",        # the SSD chunked scan (jnp or Pallas)
     "mlp",             # the block's MLP (dense or MoE)
+    "moe_dispatch",    # MoE routing: router, top-k, sort, gathers into
+                       # expert order and the weighted scatter-add back
+    "moe_experts",     # the grouped matmuls over the held experts and
+                       # their activation
     "head_loss",       # final norm, LM head logits and cross-entropy
     "optimizer",       # gradient clipping and the optimizer update
 )

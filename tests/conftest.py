@@ -70,3 +70,13 @@ def run_sub(body: str, devices: int = 8, timeout: int = 520) -> str:
     )
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr[-3000:]}"
     return r.stdout
+
+
+def pytest_configure(config):
+    """Give the benchmark tests the tiny widths of every cell's
+    configuration (``tests/bench/bench_tiny_cells.py`` adds those of the
+    cells that ``bench_tiny`` does not know) before they are collected."""
+    bench_tests = str(REPO / "tests" / "bench")
+    if bench_tests not in sys.path:
+        sys.path.insert(0, bench_tests)
+    import bench_tiny_cells  # noqa: F401

@@ -20,19 +20,23 @@ def test_moe_sharded_matches_baseline():
     p = jax.tree_util.tree_map(lambda a: a[0], p)  # drop layer dim
 
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64), jnp.float32)
-    base, aux_b = moe.moe_mlp(p, x, cfg, capacity_factor=8.0)
+    base, stats_b = moe.moe_mlp(p, x, cfg)
 
     xs = jax.device_put(x, NamedSharding(mesh, P(("data",), "model", None)))
     ps = {k: jax.device_put(v, NamedSharding(mesh, P("model", None, None)))
           for k, v in p.items() if k != "router"}
     ps["router"] = jax.device_put(p["router"], NamedSharding(mesh, P()))
-    out, aux_s = jax.jit(lambda pp, xx: moe.moe_mlp_sharded(
-        pp, xx, cfg, mesh=mesh, capacity_factor=8.0))(ps, xs)
+    out, stats_s = jax.jit(lambda pp, xx: moe.moe_mlp_sharded(
+        pp, xx, cfg, mesh=mesh))(ps, xs)
     np.testing.assert_allclose(np.asarray(base), np.asarray(out),
                                rtol=2e-5, atol=2e-5)
+    # dropless: the expert shards compute every assignment between them
+    assert int(stats_s["moe_held_rows"]) == int(stats_b["moe_held_rows"]) \
+        == 4 * 16 * 2
     # aux is a mean-based estimator: per-dp-shard aux averaged != global aux
     # exactly (nonlinear in the token partition); 2% window
-    np.testing.assert_allclose(float(aux_b), float(aux_s), rtol=2e-2)
+    np.testing.assert_allclose(float(stats_b["aux"]), float(stats_s["aux"]),
+                               rtol=2e-2)
     print("moe sharded == baseline OK")
     """)
 

@@ -262,6 +262,35 @@ def test_flash_attention_grads_match_oracle(B, S, H, KV, D, window, cap, qb,
                                    np.asarray(exp, np.float32), **TOL[dtype])
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_mla_head_dims_grads_match_oracle(dtype):
+    """MLA's heads: q and k 192 wide (qk_nope 128 + qk_rope 64), v 128, at
+    DeepSeek-V2-Lite's YaRN softmax scale, over several q and k blocks: the
+    forward, dK/dV and dQ kernels against jax.grad of the dense oracle."""
+    B, S, H, D, Dv = 1, 256, 2, 192, 128
+    ks = jax.random.split(jax.random.PRNGKey(9), 4)
+    q = jax.random.normal(ks[0], (B, H, S, D), jnp.float32).astype(dtype)
+    k = jax.random.normal(ks[1], (B, H, S, D), jnp.float32).astype(dtype)
+    v = jax.random.normal(ks[2], (B, H, S, Dv), jnp.float32).astype(dtype)
+    w = jax.random.normal(ks[3], (B, H, S, Dv), jnp.float32)
+    scale = 0.11472
+
+    def grads(attn):
+        def f(q, k, v):
+            o = attn(q, k, v)
+            return jnp.sum(o.astype(jnp.float32) * w), o
+        return jax.grad(f, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+
+    (dq, dk, dv), out = grads(lambda q, k, v: flash_attention(
+        q, k, v, scale=scale, q_block=128, kv_block=128, interpret=True))
+    (rq, rk, rv), want = grads(lambda q, k, v: ref.flash_attention_ref(
+        q, k, v, scale=scale))
+    assert out.shape == (B, H, S, Dv) and dv.shape == v.shape
+    for got, exp in ((out, want), (dq, rq), (dk, rk), (dv, rv)):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(exp, np.float32), **TOL[dtype])
+
+
 # (Sq, Sk, dk, dv, on a TPU) -> the implementation attention(impl="auto")
 # picks, as counted under repro.obs.TRACE_COUNTS
 AUTO_CASES = [
@@ -271,7 +300,7 @@ AUTO_CASES = [
     (192, 192, 64, 64, True, "dense"),      # S not a multiple of 128
     (128, 256, 64, 64, True, "dense"),      # Sq != Sk (a chunk on a cache)
     (1, 4096, 64, 64, True, "chunked"),     # decode-shaped, long cache
-    (256, 256, 192, 128, True, "dense"),    # MLA: dk != dv
+    (256, 256, 192, 128, True, "flash"),    # MLA: dk != dv
     (256, 256, 64, 64, False, "dense"),     # not a TPU
     (4096, 4096, 64, 64, False, "chunked"),
 ]

@@ -1,6 +1,6 @@
 """Hypothesis property tests on system invariants: ring-cache position
-reconstruction, MoE capacity/drop behaviour, quantization bounds, and the
-counting-mode extrapolation identity."""
+reconstruction, the dropless MoE layer's expert shares, quantization
+bounds, and the counting-mode extrapolation identity."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -47,41 +47,55 @@ def test_kv_quantization_bounded_error(seed):
     assert np.all(err <= bound + 1e-6)
 
 
-@given(st.sampled_from([1.0, 1.25, 2.0, 8.0]), st.integers(0, 1000))
-@settings(max_examples=12, deadline=None)
-def test_moe_capacity_drop_monotonic(cap, seed):
-    """Higher capacity factor ⇒ output closer to the uncapped reference
-    (dropped tokens produce zero MoE output, shrinking ||out||)."""
-    cfg = get_config("jamba-1.5-large-398b").reduced().replace(
-        num_experts=4, top_k=2, moe_d_ff=32, d_model=32)
+def _moe_layer(cfg, seed):
     p = materialize(moe_lib.moe_specs(cfg, 1), jax.random.PRNGKey(seed))
-    p = jax.tree_util.tree_map(lambda a: a[0], p)
+    return jax.tree_util.tree_map(lambda a: a[0], p)
+
+
+@given(st.integers(0, 1000), st.sampled_from([1, 2, 4]))
+@settings(max_examples=12, deadline=None)
+def test_moe_expert_shares_sum_to_the_whole_layer(seed, shares):
+    """The dropless layer told which experts it holds: the routed parts
+    that ``shares`` devices compute from their own experts, plus the
+    shared experts counted once, equal the uncut layer, and between them
+    they compute every (token, expert) assignment once."""
+    cfg = get_config("deepseek-v2-lite").reduced().replace(
+        num_experts=8, top_k=3, moe_d_ff=32, d_model=32)
+    p = _moe_layer(cfg, seed)
     x = jax.random.normal(jax.random.PRNGKey(seed + 1), (2, 16, 32))
-    ref, _ = moe_lib.moe_mlp(p, x, cfg, capacity_factor=64.0)  # effectively uncapped
-    out, _ = moe_lib.moe_mlp(p, x, cfg, capacity_factor=cap)
-    gap = float(jnp.linalg.norm(out - ref))
-    if cap >= 8.0:
-        assert gap < 1e-4  # capacity covers everything
-    # with lower capacity the output never exceeds the reference norm by drop
-    assert float(jnp.linalg.norm(out)) <= float(jnp.linalg.norm(ref)) + 1e-3
+    whole, stats = moe_lib.moe_mlp(p, x, cfg)
+    n = 8 // shares
+    part_cfg = cfg.replace(num_shared_experts=0, experts_held=n)
+    total, rows = moe_lib.dense_mlp(p["shared"], x), 0
+    for i in range(shares):
+        held = {k: (v[i * n:(i + 1) * n] if k.startswith("w_") else v)
+                for k, v in p.items() if k != "shared"}
+        out, st_i = moe_lib.moe_mlp(held, x, part_cfg.replace(
+            expert_offset=i * n))
+        total, rows = total + out, rows + int(st_i["moe_held_rows"])
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               rtol=1e-5, atol=1e-5)
+    assert rows == int(stats["moe_held_rows"]) == 2 * 16 * 3
 
 
-def test_moe_capacity_sweep_drop_rate():
-    """Ablation: token-drop fraction vs capacity factor (recorded, monotone)."""
-    cfg = get_config("deepseek-v2-236b").reduced().replace(
-        num_experts=4, top_k=2, moe_d_ff=32, d_model=32, num_shared_experts=0)
-    p = materialize(moe_lib.moe_specs(cfg, 1), jax.random.PRNGKey(0))
-    p = jax.tree_util.tree_map(lambda a: a[0], p)
-    x = jax.random.normal(jax.random.PRNGKey(1), (4, 64, 32))
-    ref, _ = moe_lib.moe_mlp(p, x, cfg, capacity_factor=64.0)
-    gaps = []
-    for cap in (0.5, 1.0, 1.5, 2.0):
-        out, _ = moe_lib.moe_mlp(p, x, cfg, capacity_factor=cap)
-        changed = jnp.any(jnp.abs(out - ref) > 1e-6, axis=-1)
-        gaps.append(float(jnp.mean(changed)))
-    # drop rate decreases with capacity
-    assert all(gaps[i] >= gaps[i + 1] - 1e-9 for i in range(len(gaps) - 1)), gaps
-    assert gaps[-1] < 0.2
+@given(st.integers(0, 1000), st.integers(0, 3))
+@settings(max_examples=8, deadline=None)
+def test_moe_batch_routed_to_one_held_expert_loses_no_token(seed, expert):
+    """Every token routed to the same held expert (the router's column for
+    it dominates): the layer computes all of them, as the every-expert
+    reference does, where a capacity-factor dispatch would drop most."""
+    cfg = get_config("deepseek-v2-lite").reduced().replace(
+        num_experts=16, top_k=1, moe_d_ff=32, d_model=32,
+        num_shared_experts=0, experts_held=4, expert_offset=4)
+    p = _moe_layer(cfg, seed)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(seed + 1), (2, 32, 32)))
+    p["router"] = p["router"].at[:, 4 + expert].set(10.0)
+    out, stats = moe_lib.moe_mlp(p, x, cfg)
+    assert int(stats["moe_held_rows"]) == 2 * 32
+    ref = moe_lib.moe_mlp_ref(p, x, cfg)
+    assert float(jnp.min(jnp.linalg.norm(out, axis=-1))) > 0.0
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-5, atol=1e-5)
 
 
 @given(st.integers(1, 40), st.floats(1.0, 100.0), st.floats(0.0, 10.0))

@@ -173,3 +173,48 @@ def test_granite_flash_train_step_fits_one_v5e(one_chip, monkeypatch):
     assert text.count("tpu_custom_call") == 3
     assert not re.search(r"\[4,(8,4|32),2048,2048\]", text)
     assert ma.temp_size_in_bytes < 5.88e9, ma.temp_size_in_bytes
+
+
+def test_deepseek_v2_lite_train_step_fits_one_v5e(one_chip, monkeypatch):
+    """The step of the benchmark's dsv2lite-train-s8k cell as it runs on a
+    TPU: DeepSeek-V2-Lite at published widths, one chip's share (the dense
+    layer and 4 MoE layers, 8 of 64 experts, an eighth of the vocabulary),
+    2 x 8192 rows, float32 AdamW, block remat; MLA through the flash
+    kernels and the experts through the grouped matmul, compiled.  It fits
+    90% of the chip (Eq. 5) and holds no S x S tensor."""
+    from repro.configs.base import get_config
+    from repro.kernels import ops
+    from repro.launch.steps import build_train_step
+    from repro.models import attention, moe
+    from repro.models import model as M
+    from repro.models.blocks import RunConfig
+    from repro.models.common import materialize
+    from repro.optim.adamw import OptConfig, init_state
+
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(moe, "_auto_impl", lambda: "gmm")
+    cfg = get_config("deepseek-v2-lite").replace(
+        num_layers=5, experts_held=8, vocab_size=12800)
+    opt = OptConfig(lr=3e-4, warmup_steps=10, total_steps=100000)
+    run = RunConfig(attn_impl="auto", remat="block")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: materialize(M.model_specs(cfg), jax.random.PRNGKey(0))))
+    state = on_chip(jax.eval_shape(lambda p: init_state(opt, p), params))
+    tok = jax.ShapeDtypeStruct((2, 8192), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(build_train_step(cfg, run, opt),
+                       donate_argnums=(0, 1)).lower(
+        params, state, {"tokens": tok, "labels": tok}).compile()
+    ma, text = compiled.memory_analysis(), compiled.as_text()
+    used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
+    assert used < 0.9 * 16 * GIB, f"{used / GIB:.2f} GiB"
+    # flash forward, dQ and dK/dV in the dense layer and in the MoE layers'
+    # loop, and the grouped matmuls (3 forward, 3 recomputed, 6 backward)
+    assert text.count("tpu_custom_call") == 18
+    assert not re.search(r"\[[0-9,]*8192,8192\]", text)
