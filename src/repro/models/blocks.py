@@ -115,7 +115,7 @@ def _mlp_forward(p, h, cfg, slot: SlotSpec, run: RunConfig):
 
 
 def slot_forward(p, h, positions, cfg: ModelConfig, slot: SlotSpec, run: RunConfig):
-    """Returns (h, cache, MoE statistics: ``{"aux", "moe_held_rows"}``)."""
+    """Returns (h, cache, MoE statistics keyed as ``moe.no_stats()``)."""
     with scope("block"):
         resid = h
         u = rms_norm(h, p["mixer_norm"], cfg.norm_eps)

@@ -138,7 +138,7 @@ def _remat(run: RunConfig, fn):
 
 def _scan_cycles(params, h, positions, cfg, run, with_cache: bool):
     """Scan the main pattern cycles. Returns (h, caches, MoE statistics
-    summed over the layers: ``{"aux", "moe_held_rows"}``)."""
+    summed over the layers, keyed as ``moe.no_stats()``)."""
     slot_names = [f"slot{i}" for i in range(len(cfg.pattern))]
     stacked = {n: params["slots"][n] for n in slot_names}
 
@@ -192,7 +192,7 @@ def cast_params(params, cfg: ModelConfig):
 def forward(params, batch, cfg: ModelConfig, run: RunConfig,
             with_cache: bool = False):
     """Full-sequence forward. Returns (logits, caches, MoE statistics
-    ``{"aux", "moe_held_rows"}`` summed over the layers)."""
+    summed over the layers, keyed as ``moe.no_stats()``)."""
     params = cast_params(params, cfg)
     h = embed_tokens(params, batch, cfg)
     h = constrain(h, run.act_sharding)
@@ -229,7 +229,9 @@ def loss_fn(params, batch, cfg: ModelConfig, run: RunConfig):
     load-balance loss. ``labels`` < 0 are ignored. For VLM inputs the
     image-prefix positions carry no labels (mask handled via label padding).
     The metrics of an MoE configuration carry ``moe_held_rows``, the
-    (token, expert) assignments its held experts computed."""
+    (token, expert) assignments its held experts computed, and
+    ``moe_overflow_layers``, the MoE layer calls whose held experts outgrew
+    the sized row buffer (``models.moe.routed_experts``)."""
     logits, _, stats = forward(params, batch, cfg, run)
     with scope("head_loss"):
         labels = batch["labels"]
@@ -243,6 +245,7 @@ def loss_fn(params, batch, cfg: ModelConfig, run: RunConfig):
     metrics = {"ce": ce, "aux": stats["aux"]}
     if cfg.has_moe:
         metrics["moe_held_rows"] = stats["moe_held_rows"]
+        metrics["moe_overflow_layers"] = stats["moe_overflow_layers"]
     return ce + cfg.aux_loss_alpha * stats["aux"], metrics
 
 

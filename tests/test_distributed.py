@@ -33,6 +33,10 @@ def test_moe_sharded_matches_baseline():
     # dropless: the expert shards compute every assignment between them
     assert int(stats_s["moe_held_rows"]) == int(stats_b["moe_held_rows"]) \
         == 4 * 16 * 2
+    # the whole expert set on one device has one buffer size; each of the
+    # 8 shards (2 data x 4 model) may outgrow its sized buffer
+    assert int(stats_b["moe_overflow_layers"]) == 0
+    assert 0 <= int(stats_s["moe_overflow_layers"]) <= 8
     # aux is a mean-based estimator: per-dp-shard aux averaged != global aux
     # exactly (nonlinear in the token partition); 2% window
     np.testing.assert_allclose(float(stats_b["aux"]), float(stats_s["aux"]),

@@ -1,7 +1,8 @@
 """DeepSeek-V2's pieces against plain computations on the CPU: MLA without
 q-LoRA under YaRN rope, the sequence-wise balance loss, the grouped matmul
-(megablox ``gmm`` in interpret mode) against XLA's ``ragged_dot``, and the
-training step's count of the rows its held experts computed."""
+(megablox ``gmm`` in interpret mode) against XLA's ``ragged_dot``, the
+sized row buffer and its full-size fallback against the plain layer, and
+the training step's count of the rows its held experts computed."""
 import re
 import sys
 from pathlib import Path
@@ -138,12 +139,102 @@ def test_gmm_matches_ragged_dot_with_gradients(held, offset):
                                    rtol=1e-4, atol=1e-4)
 
 
+def _held_share_layer(held):
+    """A 16-expert top-4 layer holding ``held`` experts (from expert 5 where
+    it holds a share), its parameters and 2 x 32 tokens."""
+    cfg = get_config("deepseek-v2-lite").reduced().replace(
+        num_experts=16, top_k=4, moe_d_ff=32, d_model=32, experts_held=held,
+        expert_offset=0 if held == 16 else 5)
+    p = materialize(moe_lib.moe_specs(cfg, 1), jax.random.PRNGKey(5))
+    p = jax.tree_util.tree_map(lambda a: a[0], p)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 32, 32))
+    return cfg, p, x
+
+
+def _value_and_grads(layer, p, x):
+    def f(p, x):
+        out, stats = layer(p, x)
+        return jnp.sum(out * out), (out, stats)
+    (gp, gx), (out, stats) = jax.grad(f, argnums=(0, 1), has_aux=True)(p, x)
+    return (out, gp, gx), stats
+
+
+@pytest.mark.parametrize("routing", ["ordinary", "all_to_held", "all_held"])
+def test_sized_buffer_matches_reference_and_full_buffer(routing,
+                                                         monkeypatch):
+    """2 of 16 experts at top-4 over 64 tokens: the sized buffer holds 64
+    rows against the full 128.  Ordinary routing fits it; a router that
+    sends every token to the held experts (128 assignments) takes the full
+    buffer, and drops nothing; with all 16 held the sized buffer is not
+    smaller and the layer has one path.  Output and every gradient equal
+    the plain layer's and the full buffer's alone."""
+    from repro.obs import TRACE_COUNTS
+
+    cfg, p, x = _held_share_layer(16 if routing == "all_held" else 2)
+    if routing == "all_to_held":
+        x = jnp.abs(x)
+        p["router"] = p["router"].at[:, 5:7].add(1.0)
+    sized = TRACE_COUNTS.counter("moe/sized_buffer")
+    before = sized.value
+    got, stats = _value_and_grads(
+        lambda p, x: moe_lib.moe_mlp(p, x, cfg, impl="ragged_dot"), p, x)
+    assert (sized.value > before) == (routing != "all_held")
+    assert stats["moe_overflow_layers"].dtype == jnp.int32
+    assert int(stats["moe_overflow_layers"]) == (routing == "all_to_held")
+    if routing == "all_to_held":
+        assert int(stats["moe_held_rows"]) == 64 * 2
+    ref, _ = _value_and_grads(
+        lambda p, x: (moe_lib.moe_mlp_ref(p, x, cfg), None), p, x)
+    monkeypatch.setattr(moe_lib, "FIT_FACTOR", 10 ** 6)
+    before = sized.value
+    full, full_stats = _value_and_grads(
+        lambda p, x: moe_lib.moe_mlp(p, x, cfg, impl="ragged_dot"), p, x)
+    assert sized.value == before
+    assert int(full_stats["moe_held_rows"]) == int(stats["moe_held_rows"])
+    for want in (ref, full):
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("factor,full_rows_saved", [(2, False),
+                                                    (10 ** 6, True)])
+def test_sized_path_saves_no_full_size_rows(factor, full_rows_saved,
+                                            monkeypatch, capsys):
+    """The residuals the backward pass keeps of the routed part: on the
+    sized path the layer's operands and index vectors, no float array of
+    the full buffer's 128 rows with a feature dimension (a differentiated
+    ``lax.cond`` would keep both branches' buffers); the full buffer alone
+    keeps its gathered rows."""
+    import jax.ad_checkpoint
+
+    cfg, p, x = _held_share_layer(2)
+    monkeypatch.setattr(moe_lib, "FIT_FACTOR", factor)
+    xf = x.reshape(-1, cfg.d_model)
+    w, idx, _ = moe_lib.route(xf, p["router"], cfg, 1)
+
+    def routed(xf, w, wg, wu, wd):
+        out, _ = moe_lib.routed_experts(
+            xf, w, idx, wg, wu, wd, e_lo=cfg.expert_offset,
+            num_experts=cfg.num_experts, impl="ragged_dot")
+        return jnp.sum(out)
+
+    capsys.readouterr()
+    jax.ad_checkpoint.print_saved_residuals(
+        routed, xf, w, p["w_gate"], p["w_up"], p["w_down"])
+    saved = capsys.readouterr().out
+    full = re.findall(r"(?:bf|f)\d+\[128,\d+", saved)
+    assert bool(full) == full_rows_saved, saved
+
+
 def test_train_step_counts_held_rows_and_names_moe_scopes():
     """A tiny DeepSeek-V2-Lite step (one chip's share: 4 of 16 experts,
     top-4): its metrics carry moe_held_rows, an int, the held experts'
-    assignments over the MoE layers; TRACE_COUNTS counts the MoE
-    implementation traced; the compiled step names moe_dispatch and
-    moe_experts inside mlp, forward and backward."""
+    assignments over the MoE layers, and moe_overflow_layers, an int32
+    scalar, the layers that outgrew the sized buffer; TRACE_COUNTS counts
+    the MoE implementation and the sized buffer traced; the compiled step
+    names moe_dispatch and moe_experts inside mlp, forward and backward."""
     from repro.launch.steps import build_train_step
     from repro.models import model as M
     from repro.models.blocks import RunConfig
@@ -159,17 +250,24 @@ def test_train_step_counts_held_rows_and_names_moe_scopes():
     state = init_state(opt, params)
     toks = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0, 256)
     batch = {"tokens": toks, "labels": toks}
-    before = TRACE_COUNTS.counter("moe/ragged_dot").value
+    counts = {n: TRACE_COUNTS.counter(f"moe/{n}")
+              for n in ("ragged_dot", "sized_buffer")}
+    before = {n: c.value for n, c in counts.items()}
     step = jax.jit(build_train_step(cfg, run, opt))
     _, _, metrics = step(params, state, batch)
-    assert TRACE_COUNTS.counter("moe/ragged_dot").value > before
+    for n, c in counts.items():
+        assert c.value > before[n], n
     rows = metrics["moe_held_rows"]
     assert rows.dtype == jnp.int32 and 0 < int(rows) <= 2 * 2 * 32 * 4
-    # the same count from the router directly
+    overflow = metrics["moe_overflow_layers"]
+    assert overflow.dtype == jnp.int32 and overflow.shape == ()
+    assert 0 <= int(overflow) <= 2
+    # the same counts from the router directly
     _, _, stats = M.forward(params, batch, cfg, run)
     assert int(stats["moe_held_rows"]) == int(rows)
+    assert int(stats["moe_overflow_layers"]) == int(overflow)
     hlo = step.lower(params, state, batch).compile().as_text()
     names = " ".join(set(re.findall(r'op_name="([^"]*)"', hlo)))
     for scope in ("moe_dispatch", "moe_experts"):
-        assert f"mlp/{scope}" in names
+        assert re.search(rf"mlp/[^ ]*{scope}", names)
         assert re.search(rf"transpose\([^ ]*{scope}", names)

@@ -215,6 +215,7 @@ def test_deepseek_v2_lite_train_step_fits_one_v5e(one_chip, monkeypatch):
     used = ma.argument_size_in_bytes + ma.temp_size_in_bytes
     assert used < 0.9 * 16 * GIB, f"{used / GIB:.2f} GiB"
     # flash forward, dQ and dK/dV in the dense layer and in the MoE layers'
-    # loop, and the grouped matmuls (3 forward, 3 recomputed, 6 backward)
-    assert text.count("tpu_custom_call") == 18
+    # loop, and the grouped matmuls (3 forward; 3 recomputed and 6
+    # transposed in the backward pass), once for each buffer size
+    assert text.count("tpu_custom_call") == 6 + 2 * 12
     assert not re.search(r"\[[0-9,]*8192,8192\]", text)
