@@ -4,12 +4,8 @@ into an in-node regime (servers colocated with their workers, B_ps = the
 fast intra-node tier) and a cross-node regime (the paper's dedicated PS
 deployment, B_ps = the narrowest spanning tier).  Plus the TPU mapping
 (grad-sync schedule masked behind compute) on both the flat pod and the
-hierarchical 2-pod DCN topology, validated against the dry-run collective
-bytes when available."""
+hierarchical 2-pod DCN topology."""
 from __future__ import annotations
-
-import json
-from pathlib import Path
 
 from repro.configs.base import ARCH_IDS, get_config, get_shape
 from repro.core import memory_model as mm, ps
@@ -71,20 +67,3 @@ def run(csv_rows):
             csv_rows.append((f"lemma32_tpu/{arch}/{label}/masked",
                              float(plan.masked),
                              f"{plan.schedule}@{plan.bottleneck_tier}"))
-
-    # cross-check against dry-run wire bytes (if the sweep has run)
-    d = Path("results/dryrun")
-    if d.exists():
-        print("\n== validation vs dry-run collective bytes (train_4k single) ==")
-        for arch in ARCH_IDS:
-            f = d / f"{arch}__train_4k__single.json"
-            if not f.exists():
-                continue
-            rec = json.loads(f.read_text())
-            if not rec.get("ok") or "derived" not in rec:
-                continue
-            wire = rec["derived"]["wire_bytes"]
-            t_wire = wire / SINGLE_POD.cluster.min_bw
-            print(f"{arch:24s} dry-run wire/chip "
-                  f"{wire/2**30:6.2f} GiB -> {t_wire:6.3f}s on ICI")
-            csv_rows.append((f"lemma32_dryrun/{arch}/wire_gib", wire / 2**30, ""))

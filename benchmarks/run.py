@@ -1,4 +1,4 @@
-"""Benchmark harness — one module per paper table/figure plus the roofline.
+"""Benchmark harness — one module per paper table/figure.
 
     PYTHONPATH=src python -m benchmarks.run [--only table2,fig2,...] [--fast]
 
@@ -12,8 +12,7 @@ import sys
 import time
 
 ALL = ("table2", "fig2", "fig3", "fig4", "lemma32", "sync", "sweep",
-       "autotune", "ilp", "dryrun", "roofline", "telemetry",
-       "serve_continuous")
+       "autotune", "ilp", "telemetry", "serve_continuous")
 
 
 def main() -> None:
@@ -49,10 +48,6 @@ def main() -> None:
             from benchmarks import autotune as m
         elif name == "ilp":
             from benchmarks import ilp_planner as m
-        elif name == "dryrun":
-            from benchmarks import dryrun_summary as m
-        elif name == "roofline":
-            from benchmarks import roofline as m
         elif name == "telemetry":
             from benchmarks import telemetry as m
         elif name == "serve_continuous":

@@ -7,10 +7,10 @@ run.  This pass makes the binding statically checkable:
 - Pass 1 collects every axis name the repo *declares* — string literals
   inside ``Mesh(...)``/``make_mesh(...)`` constructions, ``axis_names=``
   keyword tuples, and ``PartitionSpec``/``P`` literals.  The declared set
-  is repo-global: ``launch/mesh.py`` builds the meshes whose axes
-  ``distributed/collectives.py`` reduces over, and
-  ``distributed/pipeline.py``'s ``(pipe, data)`` grid declares the
-  ``pipe`` stage axis its per-stage flat meshes slice out of.
+  is repo-global: ``distributed/trainer.py`` builds the ``(data,)`` and
+  ``(nodes, data)`` meshes whose axes ``distributed/collectives.py``
+  reduces over, and ``distributed/pipeline.py``'s ``(pipe, data)`` grid
+  declares the ``pipe`` stage axis its per-stage flat meshes slice out of.
 - Pass 2 audits every collective call (``psum``, ``psum_scatter``,
   ``all_gather``, ``ppermute``, ``pmean``, ``pmax``, ``pmin``,
   ``all_to_all``, ``axis_index``):

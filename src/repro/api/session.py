@@ -199,8 +199,7 @@ class Session:
 
     def dryrun(self) -> Report:
         """Analytic dry run — plan plus the step-time roofline terms and
-        the memory-model breakdown, no compile and no training.  (The
-        heavyweight lower+compile sweep stays in ``repro.launch.dryrun``.)"""
+        the memory-model breakdown, no compile and no training."""
         p = self.resolved_plan
         pred = self._predicted()
         dp, tp = self.mesh_spec.dp, self.mesh_spec.tp

@@ -1,8 +1,9 @@
 """LLaVA-NeXT-34B — VLM: dense GQA language backbone consuming precomputed
 patch embeddings (anyres tiling). [hf:llava-hf/llava-v1.6-mistral-7b-hf]
 60L d_model=7168 56H GQA kv=8 d_ff=20480 vocab=64000. The ViT/SigLIP encoder +
-projector is the modality-frontend stub (carve-out): ``input_specs`` supplies
-(B, num_image_tokens, d_model) patch embeddings prepended to the text tokens.
+projector is the modality-frontend stub (carve-out): the batch's
+``image_embeds`` supplies (B, num_image_tokens, d_model) patch embeddings,
+prepended to the text tokens.
 """
 from repro.configs.base import ModelConfig, SlotSpec
 

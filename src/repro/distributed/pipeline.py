@@ -54,9 +54,9 @@ baseline's intact loop body (observed empirically: 1-cycle stages drift
 at ~1e-7 relative, 2-cycle stages match exactly).  ``balanced_stage_cut``
 yields ≥2-cycle stages whenever ``main_cycles(cfg) >= 2 * pipe``.
 
-Restrictions: multi-codebook embeddings, VLM image prefixes, stateful
-(error-feedback) compressors and ``unroll_layers`` are rejected — each
-breaks the contiguous-stage or element-wise-sync argument above.
+Restrictions: multi-codebook embeddings, VLM image prefixes and stateful
+(error-feedback) compressors are rejected — each breaks the
+contiguous-stage or element-wise-sync argument above.
 """
 from __future__ import annotations
 
@@ -156,10 +156,6 @@ class PipelineTrainer:
         if cfg.num_image_tokens:
             raise NotImplementedError(
                 "pipeline trainer does not take VLM image prefixes")
-        if run.unroll_layers:
-            raise NotImplementedError(
-                "pipeline stages scan their cycle slice; unroll_layers "
-                "is incompatible")
         if run.microbatch:
             raise ValueError(
                 "set n_microbatch on the trainer, not run.microbatch — "

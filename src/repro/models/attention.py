@@ -106,7 +106,7 @@ def dense_attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0):
 
 
 def chunked_attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
-                      kv_block=1024, q_block=2048, unroll_kv=False):
+                      kv_block=1024, q_block=2048):
     """Triangular blocked online-softmax attention — the XLA flash reference.
 
     Outer *unrolled* loop over query blocks (so each block sees a static KV
@@ -164,16 +164,7 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
         m0 = jnp.full((B, KV, G, q_block), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, q_block), jnp.float32)
         a0 = jnp.zeros((B, KV, G, q_block, dv), jnp.float32)
-        if unroll_kv:
-            # counting mode for the dry-run FLOP accounting: XLA's
-            # cost_analysis does not multiply while-body costs by trip count,
-            # so the roofline lowers use a physically-unrolled KV loop.
-            carry = (m0, l0, a0)
-            for t in range(nblk):
-                carry, _ = step(carry, (kb[t], vb[t], pb[t]))
-            m_f, l_f, acc = carry
-        else:
-            (m_f, l_f, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kb, vb, pb))
+        (m_f, l_f, acc), _ = jax.lax.scan(step, (m0, l0, a0), (kb, vb, pb))
         out = acc / jnp.maximum(l_f, 1e-30)[..., None]
         return out.transpose(0, 3, 1, 2, 4).reshape(B, q_block, H, dv)
 
@@ -182,8 +173,9 @@ def chunked_attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
     return out[:, :Sq].astype(v.dtype)
 
 
-# the name each impl is counted under in ``TRACE_COUNTS``
-_COUNTED = {"pallas": "flash", "counting": "chunked"}
+# the impls ``attention`` runs, each with the name it is counted under in
+# ``TRACE_COUNTS``
+_COUNTED = {"pallas": "flash", "dense": "dense", "chunked": "chunked"}
 
 
 def _on_tpu() -> bool:
@@ -206,19 +198,16 @@ def attention(q, k, v, q_pos, k_pos, *, scale, window=0, cap=0.0,
     with scope("attention_core"):
         if impl == "auto":
             impl = _auto_impl(q, k, v)
-        TRACE_COUNTS.inc("attention/" + _COUNTED.get(impl, impl))
+        if impl not in _COUNTED:
+            raise ValueError(f"unknown attention impl {impl!r}; known: "
+                             f"auto, {', '.join(_COUNTED)}")
+        TRACE_COUNTS.inc("attention/" + _COUNTED[impl])
         if impl == "pallas":
             # the Pallas flash kernel: compiled on a TPU, run by the Pallas
             # interpreter on any other backend (kernels/ops.py decides)
             from repro.kernels import ops as kops
             return kops.flash_attention(q, k, v, q_pos, k_pos, scale=scale,
                                         window=window, cap=cap)
-        if impl == "counting":
-            # dry-run FLOP-accounting mode: big unrolled blocks, no while
-            # loops
-            return chunked_attention(q, k, v, q_pos, k_pos, scale=scale,
-                                     window=window, cap=cap, kv_block=8192,
-                                     q_block=8192, unroll_kv=True)
         f = dense_attention if impl == "dense" else chunked_attention
         kw = {} if impl == "dense" else {"kv_block": kv_block}
         return f(q, k, v, q_pos, k_pos, scale=scale, window=window, cap=cap,

@@ -5,8 +5,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Any, Dict
 
-import jax
-
 from repro.configs.base import ModelConfig, SlotSpec
 from repro.models import attention as attn
 from repro.models import moe as moe_lib
@@ -19,32 +17,10 @@ from repro.obs.scopes import scope
 class RunConfig:
     """Runtime (non-architecture) knobs — what the paper's planner tunes."""
 
-    attn_impl: str = "auto"  # dense | chunked | pallas | auto
+    attn_impl: str = "auto"  # auto | pallas | dense | chunked
     remat: str = "block"  # none | block
-    seq_parallel: bool = False
     microbatch: int = 0  # >0: gradient-accumulation microbatch size
-    # concrete NamedShardings injected by the launcher (None on single host):
-    act_sharding: Any = None  # residual stream (B, S, D)
-    kv_block: int = 1024
-    q_block: int = 2048
-    # dry-run FLOP-accounting mode: python-unroll the layer loops so that
-    # cost_analysis (which ignores while-loop trip counts) sees every op
-    unroll_layers: bool = False
-    # --- beyond-paper optimizations (§Perf), all off by default ---
-    logit_sharding: Any = None  # keep logits seq-sharded through the CE path
-    moe_mesh: Any = None  # shard_map expert-parallel MoE over this mesh
-    moe_axis: str = "model"  # expert axis name within moe_mesh
-    pad_heads_to: int = 0  # zero-pad Q heads so TP divides them (llava/arctic)
-    grad_shardings: Any = None  # pytree of NamedShardings: force reduce-scatter
-    # grad sync onto the ZeRO layout instead of GSPMD's all-reduce choice
     cache_scatter: bool = False  # decode cache write via scatter, not one-hot
-    bf16_grads: bool = False  # mixed precision: grads computed/synced in bf16
-
-
-def constrain(x, sharding):
-    if sharding is None:
-        return x
-    return jax.lax.with_sharding_constraint(x, sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -99,18 +75,14 @@ def _mixer_forward(p, h, positions, cfg, slot: SlotSpec, run: RunConfig):
                                 impl=run.attn_impl)
 
 
-def _mlp_forward(p, h, cfg, slot: SlotSpec, run: RunConfig):
+def _mlp_forward(p, h, cfg, slot: SlotSpec):
     """Returns (out, MoE statistics: ``moe.no_stats()`` for a dense MLP)."""
     with scope("mlp"):
         if slot.mlp == "dense":
             return moe_lib.dense_mlp(p, h), moe_lib.no_stats()
-        moe_fn, kw = moe_lib.moe_mlp, {}
-        if run.moe_mesh is not None:
-            moe_fn = moe_lib.moe_mlp_sharded
-            kw = dict(mesh=run.moe_mesh, axis=run.moe_axis)
         if slot.mlp == "moe":
-            return moe_fn(p, h, cfg, **kw)
-        y_moe, stats = moe_fn(p["moe"], h, cfg, **kw)
+            return moe_lib.moe_mlp(p, h, cfg)
+        y_moe, stats = moe_lib.moe_mlp(p["moe"], h, cfg)
         return moe_lib.dense_mlp(p["dense"], h) + y_moe, stats
 
 
@@ -122,16 +94,16 @@ def slot_forward(p, h, positions, cfg: ModelConfig, slot: SlotSpec, run: RunConf
         u, cache = _mixer_forward(p["mixer"], u, positions, cfg, slot, run)
         if cfg.use_post_norm:
             u = rms_norm(u, p["mixer_post_norm"], cfg.norm_eps)
-        h = constrain(resid + u, run.act_sharding)
+        h = resid + u
 
         stats = moe_lib.no_stats()
         if "mlp_norm" in p:
             resid = h
             u = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-            u, stats = _mlp_forward(p["mlp"], u, cfg, slot, run)
+            u, stats = _mlp_forward(p["mlp"], u, cfg, slot)
             if cfg.use_post_norm:
                 u = rms_norm(u, p["mlp_post_norm"], cfg.norm_eps)
-            h = constrain(resid + u, run.act_sharding)
+            h = resid + u
         return h, cache, stats
 
 
@@ -160,7 +132,7 @@ def slot_decode(p, h, pos, cache, cfg: ModelConfig, slot: SlotSpec, run: RunConf
     if "mlp_norm" in p:
         resid = h
         u = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-        u, _ = _mlp_forward(p["mlp"], u, cfg, slot, run)
+        u, _ = _mlp_forward(p["mlp"], u, cfg, slot)
         if cfg.use_post_norm:
             u = rms_norm(u, p["mlp_post_norm"], cfg.norm_eps)
         h = resid + u
@@ -192,7 +164,7 @@ def slot_extend(p, h, pos0, cache, cfg: ModelConfig, slot: SlotSpec,
     if "mlp_norm" in p:
         resid = h
         u = rms_norm(h, p["mlp_norm"], cfg.norm_eps)
-        u, _ = _mlp_forward(p["mlp"], u, cfg, slot, run)
+        u, _ = _mlp_forward(p["mlp"], u, cfg, slot)
         if cfg.use_post_norm:
             u = rms_norm(u, p["mlp_post_norm"], cfg.norm_eps)
         h = resid + u

@@ -2,22 +2,17 @@
 init and logical sharding axes), norms, rope, softcap.
 
 A model's ``param_specs(config)`` returns a pytree whose leaves are
-:class:`ParamSpec`. The same tree is used to
-  * materialize real parameters (``materialize(specs, key)``),
-  * produce abstract ``jax.ShapeDtypeStruct`` stand-ins with shardings for the
-    multi-pod dry-run (``abstractify(specs, mesh, rules)``),
-  * derive per-parameter ``PartitionSpec`` from logical axis names
-    (``partition_specs(specs, rules)``).
+:class:`ParamSpec`; ``materialize(specs, key)`` makes real parameters from
+it, and ``param_count`` / ``param_bytes`` size it.
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding, PartitionSpec as P
 
 # ---------------------------------------------------------------------------
 # ParamSpec
@@ -68,29 +63,6 @@ def materialize(specs, key: jax.Array, dtype_override: Optional[str] = None):
         return (jax.random.normal(k, spec.shape, jnp.float32) * std).astype(dt)
 
     return jax.tree_util.tree_map_with_path(init_leaf, specs, is_leaf=_is_spec)
-
-
-def partition_specs(specs, rules: Dict[str, Any]):
-    """Map logical axes -> mesh PartitionSpec via ``rules`` dict."""
-
-    def leaf(spec: ParamSpec):
-        return P(*(rules.get(a) if a is not None else None for a in spec.axes))
-
-    return jax.tree_util.tree_map(leaf, specs, is_leaf=_is_spec)
-
-
-def abstractify(specs, mesh, rules, dtype_override: Optional[str] = None):
-    """ShapeDtypeStructs with NamedShardings attached (no allocation)."""
-    pspecs = partition_specs(specs, rules)
-
-    def leaf(spec: ParamSpec, ps):
-        return jax.ShapeDtypeStruct(
-            spec.shape,
-            jnp.dtype(dtype_override or spec.dtype),
-            sharding=NamedSharding(mesh, ps),
-        )
-
-    return jax.tree_util.tree_map(leaf, specs, pspecs, is_leaf=_is_spec)
 
 
 def param_count(specs) -> int:

@@ -19,9 +19,8 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, SlotSpec
 from repro.kernels import FLASH_RESIDUALS
-from repro.models.blocks import (RunConfig, constrain, slot_cache_specs,
-                                 slot_decode, slot_extend, slot_forward,
-                                 slot_specs)
+from repro.models.blocks import (RunConfig, slot_cache_specs, slot_decode,
+                                 slot_extend, slot_forward, slot_specs)
 from repro.models.common import (ParamSpec, cross_entropy, rms_norm, softcap)
 from repro.models.moe import add_stats, no_stats
 from repro.obs.scopes import scope
@@ -152,21 +151,6 @@ def _scan_cycles(params, h, positions, cfg, run, with_cache: bool):
 
     body = _remat(run, cycle)
 
-    if run.unroll_layers:
-        n = main_cycles(cfg)
-        caches_list, total = [], no_stats()
-        for i in range(n):
-            cp = jax.tree_util.tree_map(lambda a: a[i], stacked)
-            h, (c, stats) = body(h, cp)
-            total = add_stats(total, stats)
-            if with_cache:
-                caches_list.append(c)
-        caches = (
-            jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *caches_list)
-            if with_cache else None
-        )
-        return h, caches, total
-
     def scan_body(h, cycle_params):
         h, (caches, stats) = body(h, cycle_params)
         return h, (caches if with_cache else None, stats)
@@ -195,7 +179,6 @@ def forward(params, batch, cfg: ModelConfig, run: RunConfig,
     summed over the layers, keyed as ``moe.no_stats()``)."""
     params = cast_params(params, cfg)
     h = embed_tokens(params, batch, cfg)
-    h = constrain(h, run.act_sharding)
     B, S = h.shape[:2]
     positions = jnp.broadcast_to(jnp.arange(S)[None], (B, S))
 
@@ -214,10 +197,6 @@ def forward(params, batch, cfg: ModelConfig, run: RunConfig,
     with scope("head_loss"):
         h = rms_norm(h, params["final_norm"], cfg.norm_eps)
         logits = lm_logits(params, h, cfg)
-        # §Perf: keep logits sequence-sharded through the CE path (prevents
-        # a full-vocab unsharded materialization, ~40 GB f32 for qwen2-72b
-        # train)
-        logits = constrain(logits, run.logit_sharding)
     all_caches = {"slots": caches}
     if cfg.first_k_dense:
         all_caches["prelude"] = pre_caches
@@ -262,7 +241,6 @@ def decode_step(params, tokens, pos, caches, cfg: ModelConfig, run: RunConfig):
     """
     params = cast_params(params, cfg)
     h = embed_tokens(params, {"tokens": tokens}, cfg)
-    B = h.shape[0]
 
     new_caches: Dict[str, Any] = {}
     if cfg.first_k_dense:
@@ -290,15 +268,7 @@ def decode_step(params, tokens, pos, caches, cfg: ModelConfig, run: RunConfig):
             out_cache[n] = nc
         return h, out_cache
 
-    if run.unroll_layers:
-        outs = []
-        for i in range(main_cycles(cfg)):
-            xs_i = jax.tree_util.tree_map(lambda a: a[i], stacked)
-            h, oc = cycle(h, xs_i)
-            outs.append(oc)
-        new_slot_caches = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *outs)
-    else:
-        h, new_slot_caches = jax.lax.scan(cycle, h, stacked)
+    h, new_slot_caches = jax.lax.scan(cycle, h, stacked)
     new_caches["slots"] = new_slot_caches
 
     h = rms_norm(h, params["final_norm"], cfg.norm_eps)

@@ -14,10 +14,8 @@ receive more than that runs the same computation on a buffer sized for
 the most they can receive, ``T * min(k, held)`` (counted in
 ``moe_overflow_layers``), and where the sized buffer is not smaller than
 that (every expert held) the layer has the full buffer alone.  The
-grouped matmul computes only the rows the group sizes cover.  Expert
-parallelism is the sum of these partial results over the devices that
-hold the experts (:func:`moe_mlp_sharded`); the shared experts are
-computed in full.
+grouped matmul computes only the rows the group sizes cover.  The shared
+experts are computed in full.
 
 Dispatch is gather/scatter (memory ops), not a one-hot einsum, and the
 grouped matmul's FLOPs are those of the rows it covers, so the compute
@@ -266,56 +264,6 @@ def moe_mlp(p, x, cfg: ModelConfig, *, impl: str = "auto"):
         xf, w, idx, p["w_gate"], p["w_up"], p["w_down"],
         e_lo=cfg.expert_offset, num_experts=cfg.num_experts, impl=impl)
     out = out.astype(x.dtype).reshape(B, S, D)
-    if cfg.num_shared_experts:
-        out = out + dense_mlp(p["shared"], x)
-    return out, {"aux": aux, **stats}
-
-
-def moe_mlp_sharded(p, x, cfg: ModelConfig, *, mesh, axis: str = "model",
-                    impl: str = "auto"):
-    """Expert-parallel MoE via shard_map (§Perf optimization).
-
-    Each expert shard all-gathers the (sequence-sharded) tokens once, runs
-    :func:`routed_experts` over its local experts, and the partial outputs
-    are combined with one reduce-scatter back to the sequence-sharded
-    layout: exactly 2 collectives per MoE layer instead of the replicated
-    scatters and all-reduces GSPMD emits for a sharded expert buffer."""
-    from jax.sharding import PartitionSpec as P
-
-    B, S, D = x.shape
-    tp = mesh.shape[axis]
-    E = cfg.num_experts
-    assert E % tp == 0, (E, tp)
-    assert cfg.held_experts == E, "shard the whole expert set, not a share"
-    E_loc = E // tp
-    dp = tuple(a for a in mesh.axis_names if a != axis)
-
-    def body(xl, router_w, wg, wu, wd):
-        # xl (B_loc, S/tp, D) -> gather full local-replica token set
-        x_full = jax.lax.all_gather(xl, axis, axis=1, tiled=True)  # (B_loc,S,D)
-        Bl, Sl, _ = x_full.shape
-        xf = x_full.reshape(Bl * Sl, D)
-        with scope("moe_dispatch"):
-            w, idx, aux = route(xf, router_w, cfg, Bl if cfg.seq_aux else 1)
-        out, stats = routed_experts(
-            xf, w, idx, wg, wu, wd, e_lo=jax.lax.axis_index(axis) * E_loc,
-            num_experts=E, impl=impl)
-        out = out.reshape(Bl, Sl, D).astype(x.dtype)
-        # sum partials across expert shards, landing seq-sharded again
-        out = jax.lax.psum_scatter(out, axis, scatter_dimension=1, tiled=True)
-        aux = jax.lax.pmean(aux, axis)
-        stats = jax.lax.psum(stats, axis)
-        for a in dp:
-            aux = jax.lax.pmean(aux, a)
-            stats = jax.lax.psum(stats, a)
-        return out, aux, stats
-
-    out, aux, stats = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(dp, axis, None), P(), P(axis, None, None),
-                  P(axis, None, None), P(axis, None, None)),
-        out_specs=(P(dp, axis, None), P(), P()), check_vma=False,
-    )(x, p["router"], p["w_gate"], p["w_up"], p["w_down"])
     if cfg.num_shared_experts:
         out = out + dense_mlp(p["shared"], x)
     return out, {"aux": aux, **stats}

@@ -329,6 +329,26 @@ def test_auto_attention_dispatch(monkeypatch, sq, sk, dk, dv, tpu, impl):
         {n: float(n == impl) for n in names}
 
 
+@pytest.mark.parametrize("impl", ["counting", "flash", "Pallas"])
+def test_attention_rejects_unknown_impl(impl):
+    """A value outside auto | pallas | dense | chunked is refused, not run
+    as another path, and counted under no name."""
+    from repro.models import attention as A
+    from repro.obs import TRACE_COUNTS
+
+    sds = jax.ShapeDtypeStruct
+    q = sds((1, 128, 4, 64), jnp.bfloat16)
+    kv = sds((1, 128, 2, 64), jnp.bfloat16)
+    pos = sds((1, 128), jnp.int32)
+    names = ("flash", "dense", "chunked")
+    before = [TRACE_COUNTS.counter(f"attention/{n}").value for n in names]
+    with pytest.raises(ValueError, match="auto, pallas, dense, chunked"):
+        jax.eval_shape(lambda *a: A.attention(*a, scale=0.125, impl=impl),
+                       q, kv, kv, pos, pos)
+    assert [TRACE_COUNTS.counter(f"attention/{n}").value
+            for n in names] == before
+
+
 def test_auto_attention_on_tpu_trains_through_the_kernel(monkeypatch):
     """With the TPU probe on, impl="auto" runs the differentiable kernel
     (interpreted here): output and gradients equal the dense path's, and

@@ -48,22 +48,6 @@ def test_cache_scatter_matches_onehot_mla():
     np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
 
 
-def test_logit_sharding_noop_on_single_device():
-    """The logit constraint must not change values (single-device: no-op
-    sharding, value equality is exact)."""
-    cfg = get_config("granite-3-2b").reduced()
-    params = materialize(M.model_specs(cfg), jax.random.PRNGKey(0))
-    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 16), 0, cfg.vocab_size)
-    run_a = RunConfig(attn_impl="dense", remat="none")
-    la, _, _ = M.forward(params, {"tokens": toks}, cfg, run_a)
-    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
-    mesh = jax.make_mesh((1, 1), ("data", "model"), (AxisType.Auto,) * 2)
-    run_b = RunConfig(attn_impl="dense", remat="none",
-                      logit_sharding=NamedSharding(mesh, P(None, None, None)))
-    lb, _, _ = M.forward(params, {"tokens": toks}, cfg, run_b)
-    np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-
-
 def test_int8_kv_cache_close_to_bf16():
     """int8-quantized KV cache: greedy decode tokens should match and logits
     stay close to the bf16-cache path."""

@@ -179,7 +179,7 @@ for strat in ("all_reduce", "reduce_scatter_all_gather", "parameter_server"):
     np.testing.assert_allclose(float(m1["loss"]), float(m2["loss"]),
                                rtol=1e-5, atol=1e-6)
     # Adam normalizes by sqrt(v): near-zero grads amplify cross-shard
-    # reduction-order noise; same window as test_distributed's sharded step
+    # reduction-order noise
     for a, b_ in zip(jax.tree_util.tree_leaves(p1),
                      jax.tree_util.tree_leaves(p2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),

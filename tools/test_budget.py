@@ -48,11 +48,7 @@ DEFAULT_LINT_BUDGET_S = 120.0
 
 # Fast tests allowed to spawn subprocesses: (file, test-name) with
 # "*" = every test in the file.  Keep each entry justified.
-ALLOW_FAST_SUBPROCESS: Set[Tuple[str, str]] = {
-    # pre-existing bounded re-exec tests: tiny graphs, one subprocess each,
-    # they ARE the distributed-correctness fast coverage
-    ("test_distributed.py", "*"),
-}
+ALLOW_FAST_SUBPROCESS: Set[Tuple[str, str]] = set()
 
 
 def _is_slow_marker(dec: ast.expr) -> bool:
